@@ -807,7 +807,8 @@ def serve(tp):
 r1, o1, g1, m1 = serve(1)
 r2, o2, g2, m2 = serve(2)
 print(json.dumps({{
-    "devices": len(jax.devices()), "mesh_active": m2,
+    "platform": jax.default_backend(), "devices": len(jax.devices()),
+    "mesh_active": m2,
     "tp1_tokens_per_s": round(r1, 2), "tp2_tokens_per_s": round(r2, 2),
     "tp2_vs_tp1": round(r2 / r1, 3), "tp_parity": o1 == o2,
     "tp2_gating": g2}}))
@@ -854,6 +855,11 @@ def tp_bench(quick=False) -> dict:
     8 forced host devices (subprocess — jax locks the device count at
     init), bitwise tp parity, and the bytes-per-device fit rows.
 
+    The child is pinned to the CPU (``JAX_PLATFORMS=cpu``): this parent
+    has already run engines and holds any accelerator, and a chip
+    belongs to one process. Tensor parallelism over real chips is
+    ``chip_smoke.py --chips 4``.
+
     CPU caveat: host "devices" are threads on the same cores, so tp=2
     adds collective overhead without adding FLOPs — the gate is a
     not-pathologically-slower floor (``--tp-floor``), not a speedup
@@ -867,6 +873,7 @@ def tp_bench(quick=False) -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in (env.get("PYTHONPATH"), "src") if p)
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", prog], env=env,
                          capture_output=True, text=True, timeout=1800)
     if out.returncode != 0:

@@ -33,6 +33,7 @@ unfused STE rules via the fused op's custom VJP.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import jax
@@ -382,20 +383,27 @@ def perturb_analog_weights(params, labels, key: jax.Array, model: str,
     leaves, treedef = jax.tree_util.tree_flatten(params)
     lab_leaves = jax.tree_util.tree_leaves(labels)
     assert len(leaves) == len(lab_leaves)
-    out = []
-    for i, (leaf, lab) in enumerate(zip(leaves, lab_leaves)):
-        if lab == "analog_weight":
-            k = jax.random.fold_in(key, i)
-            # stacked scan weights [L, in, out]: channel axis is -2 regardless
-            flat = leaf.reshape((-1,) + leaf.shape[-2:])
-            ks = jax.random.split(k, flat.shape[0])
-            pert = jax.vmap(
-                lambda w, kk: noise_lib.apply_eval_noise(kk, w, model, gamma)
-            )(flat, ks)
-            out.append(pert.reshape(leaf.shape))
-        else:
-            out.append(leaf)
+    out = [_perturb_leaf(leaf, jax.random.fold_in(key, i), model, gamma)
+           if lab == "analog_weight" else leaf
+           for i, (leaf, lab) in enumerate(zip(leaves, lab_leaves))]
     return jax.tree_util.tree_unflatten(treedef, out)
+
+
+@functools.partial(jax.jit, static_argnames=("model", "gamma"))
+def _perturb_leaf(leaf, key, model: str, gamma: float):
+    """One analog weight leaf's perturbation, one key per stacked layer.
+
+    Jitted so the elementwise noise chain fuses: run op by op, a
+    full-width leaf (llama-3.2-1b's stacked gate_up is 2 GiB in f32)
+    materializes several leaf-sized temporaries and overflows one chip.
+    """
+    # stacked scan weights [L, in, out]: channel axis is -2 regardless
+    flat = leaf.reshape((-1,) + leaf.shape[-2:])
+    ks = jax.random.split(key, flat.shape[0])
+    pert = jax.vmap(
+        lambda w, kk: noise_lib.apply_eval_noise(kk, w, model, gamma)
+    )(flat, ks)
+    return pert.reshape(leaf.shape)
 
 
 def sample_noise_instances(params, labels, key: jax.Array, model: str):
@@ -457,7 +465,8 @@ def pack_int4_weights(params, labels=None, bits: int = 4):
     """Serving-side transform: precompute the packed-int4 carriers.
 
     Walks every analog linear site and attaches an ``"int4"`` sub-dict —
-    ``packed`` [.., K, N//2] uint8 two-nibble weights, ``scale`` [.., N]
+    ``packed`` [.., K, ceil(N/256)*128] uint8 two-nibble weights in the
+    ``kernels.ref.pack_int4`` layout, ``scale`` [.., N]
     per-column dequant scales, ``colmax`` [.., N] per-column absmax of the
     *dequantized* weights (so the runtime ADC bound matches the unfused RTN
     path bit-for-bit). ``analog_linear``'s ``int4_serve`` path consumes
@@ -477,21 +486,6 @@ def pack_int4_weights(params, labels=None, bits: int = 4):
     Training pytrees are untouched — this is an opt-in deployment transform,
     like :func:`quantize_for_digital`.
     """
-    def pack_site(w):
-        flat = w.reshape((-1,) + w.shape[-2:])
-
-        def one(wk):
-            w_int, scale = quant.rtn_quantize(wk.astype(jnp.float32), bits)
-            deq = quant.rtn_dequantize(w_int, scale)
-            return (kref.pack_int4(w_int), scale[0],
-                    jnp.max(jnp.abs(deq), axis=0))
-
-        packed, scale, colmax = jax.vmap(one)(flat)
-        lead = w.shape[:-2]
-        return {"packed": packed.reshape(lead + packed.shape[1:]),
-                "scale": scale.reshape(lead + scale.shape[1:]),
-                "colmax": colmax.reshape(lead + colmax.shape[1:])}
-
     def walk(p, lab):
         if not isinstance(p, dict):
             return p
@@ -502,10 +496,30 @@ def pack_int4_weights(params, labels=None, bits: int = 4):
         else:
             is_site = "kernel" in p and "input_range" in p
         if is_site and p["kernel"].shape[-1] % 2 == 0:
-            out["int4"] = pack_site(p["kernel"])
+            out["int4"] = _pack_int4_site(p["kernel"], bits)
         return out
 
     return walk(params, labels)
+
+
+@functools.partial(jax.jit, static_argnames=("bits",))
+def _pack_int4_site(w, bits: int):
+    """One site's int4 carriers (see :func:`pack_int4_weights`). Jitted
+    so the quantize/pack chain fuses: run op by op, a full-width leaf
+    materializes several leaf-sized temporaries and overflows one chip."""
+    flat = w.reshape((-1,) + w.shape[-2:])
+
+    def one(wk):
+        w_int, scale = quant.rtn_quantize(wk.astype(jnp.float32), bits)
+        deq = quant.rtn_dequantize(w_int, scale)
+        return (kref.pack_int4(w_int), scale[0],
+                jnp.max(jnp.abs(deq), axis=0))
+
+    packed, scale, colmax = jax.vmap(one)(flat)
+    lead = w.shape[:-2]
+    return {"packed": packed.reshape(lead + packed.shape[1:]),
+            "scale": scale.reshape(lead + scale.shape[1:]),
+            "colmax": colmax.reshape(lead + colmax.shape[1:])}
 
 
 def quantize_for_digital(params, labels, bits: int = 4):

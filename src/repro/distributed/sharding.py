@@ -26,7 +26,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 
 _state = threading.local()
@@ -39,7 +39,18 @@ def _active():
 
 @contextlib.contextmanager
 def activate(mesh: Mesh, rules: dict[str, Any]):
-    """Enable shard_hint / spec resolution inside the block."""
+    """Enable shard_hint / spec resolution inside the block.
+
+    The rules are GSPMD hints, so ``mesh`` must have Auto axes
+    (``launch.mesh.auto_mesh``); an Explicit mesh — ``jax.make_mesh``'s
+    default — is refused here, before a gather or a constraint deep in
+    the model fails on it.
+    """
+    if any(t == AxisType.Explicit for t in getattr(mesh, "axis_types", ())):
+        raise ValueError(
+            "distributed.sharding needs a mesh with Auto axes (its rules "
+            "are with_sharding_constraint hints); got axis_types="
+            f"{mesh.axis_types} — build it with launch.mesh.auto_mesh")
     prev = _active()
     _state.ctx = {"mesh": mesh, "rules": dict(rules)}
     try:
@@ -452,12 +463,17 @@ def shard_caches_for_serve(mesh: Mesh, caches):
         return jax.device_put(caches, named(cache_spec_tree(caches)))
 
 
-def serve_tp_unsupported(cfg, acfg, tp: int) -> Optional[str]:
+def serve_tp_unsupported(cfg, acfg, tp: int, *, paged: bool,
+                         chunk: int) -> Optional[str]:
     """Why ``tp``-way tensor parallelism cannot serve this config, or None.
 
     The honest-gating seam for ``ServeEngine``: a reason string here
     becomes ``gating_reasons["tensor_parallel"]`` and the engine falls
     back to tp=1 — never a silent downgrade, never a wrong answer.
+    ``paged`` says whether the engine's attention runs on the paged pool
+    and ``chunk`` is its prefill chunk length: together they decide
+    which routes reach a ``pallas_call``, which GSPMD cannot partition
+    without ``shard_map`` wiring. Only those routes are refused.
     """
     if tp <= 1:
         return None
@@ -470,12 +486,19 @@ def serve_tp_unsupported(cfg, acfg, tp: int) -> Optional[str]:
         return ("Pallas kernels are single-device (pallas_call does not "
                 "partition under GSPMD without shard_map wiring) — serve "
                 "with use_pallas=False under tensor parallelism")
-    from repro.kernels import dispatch    # lazy: kernels never import us
-    if not dispatch.partition_safe():
-        return ("the default attention dispatch routes to pallas_call "
-                "kernels on this backend, which GSPMD cannot partition "
-                "without shard_map wiring — tensor-parallel serving runs "
-                "on the reference impls (CPU/GPU backends)")
+    from repro.kernels import dispatch, ops    # lazy: kernels never import us
+    if dispatch.on_tpu():
+        if paged:
+            return ("on TPU the paged pool's attention runs the paged "
+                    "flash Pallas kernels, which GSPMD cannot partition "
+                    "without shard_map wiring — serve tensor-parallel on "
+                    "the contiguous cache (paged=False)")
+        if (cfg.family in ("ssm", "hybrid")
+                and ops.ssd_uses_kernel(chunk, ops.SSD_CHUNK)):
+            return (f"on TPU a prefill chunk of {chunk} tokens runs the "
+                    "ssd_scan Pallas kernel, which GSPMD cannot partition "
+                    "without shard_map wiring — pick a chunk that is not "
+                    f"a multiple of {ops.SSD_CHUNK}")
     if cfg.family in ("dense", "moe", "hybrid"):
         kv = getattr(cfg, "num_kv_heads", 0) or cfg.num_heads
         if cfg.num_heads % tp:

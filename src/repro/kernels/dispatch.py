@@ -50,29 +50,17 @@ from repro.kernels.paged_prefill import paged_flash_prefill
 PREFILL_BLOCKS = (256, 256, 512)
 DECODE_BM = 8
 
+# Query rows (chunk columns x query heads) one paged flash-prefill call
+# scores: the kernel holds their [rows, hd] queries, f32 accumulator and
+# [rows, bs*KV] scores in VMEM at once. 4096 rows (a 128-token chunk at
+# 32 heads, blocks of 16 x 8 kv heads) fit a v5e's 16 MiB scoped VMEM and
+# 6144 do not (tests/test_chip_compile.py); longer chunks run in pieces.
+PREFILL_MAX_ROWS = 4096
+
 
 def on_tpu() -> bool:
     """True when the default JAX backend is TPU (compiled kernels)."""
     return jax.default_backend() == "tpu"
-
-
-def partition_safe() -> bool:
-    """True when the *default* dispatch routes may run inside a
-    GSPMD-partitioned jit (tensor-parallel serving).
-
-    Off-TPU the default attention impls are the pure ``jnp``/``lax``
-    reference paths, which the partitioner splits like any other jaxpr.
-    On TPU the defaults are ``pallas_call`` kernels — opaque to GSPMD,
-    which would fall back to replicating their operands per device —
-    so tensor-parallel serving requires ``shard_map`` wiring that does
-    not exist yet. ``distributed.sharding.serve_tp_unsupported`` gates
-    on this (the honest-gating seam): TP engines on TPU fall back to
-    tp=1 with an explicit reason rather than silently serving at
-    replicated-kernel speed. The fused/packed MVM paths are gated
-    separately via ``AnalogConfig.use_pallas``, which routes through
-    ``pallas_call`` on every backend (interpret mode off-TPU).
-    """
-    return not on_tpu()
 
 
 def use_fused(cfg) -> bool:
@@ -201,15 +189,17 @@ def int4_mvm_packed(x_q: jax.Array, w_packed: jax.Array, scale: jax.Array, *,
     """``x_q @ dequant(unpack(w_packed), scale)`` via the packed-int4 kernel.
 
     ``x_q`` is already DAC-quantized (the digital periphery's job on this
-    path); ``w_packed`` holds two int4 nibbles per byte [K, N//2] — the
-    format ``core.analog.pack_int4_weights`` precomputes once per deployment
-    so decode reads weights at int4 bandwidth; ``scale`` the per-column
-    dequant scales [N]. Output quantization is applied by the caller.
-    Eval/serve-only — no autodiff rule.
+    path); ``w_packed`` holds two int4 nibbles per byte in the
+    ``ref.pack_int4`` layout [K, ceil(N/256)*128] — the format
+    ``core.analog.pack_int4_weights`` precomputes once per deployment so
+    decode reads weights at int4 bandwidth; ``scale`` the per-column
+    dequant scales [N], which also fix the output width. Output
+    quantization is applied by the caller. Eval/serve-only — no autodiff
+    rule.
     """
     x2, lead = flatten_batch(x_q)
     m, kdim = x2.shape
-    n = w_packed.shape[-1] * 2
+    n = scale.shape[-1]
     bm, bn, bk = block_shape or select_blocks(m, kdim, n)
     y = int4_matmul(x2, w_packed, scale.reshape(-1), bm=bm, bn=bn, bk=bk,
                     interpret=not on_tpu())
@@ -264,14 +254,22 @@ def paged_prefill_attention(q: jax.Array, kp: jax.Array, vp: jax.Array,
     decode op: Pallas kernel on TPU, ``lax.scan`` oracle elsewhere (its
     per-block ``lax.cond`` skips dead blocks at runtime, so active-length
     scaling holds on CPU too); ``impl`` = ``"kernel"`` / ``"ref"``
-    overrides (interpret-mode off-TPU for the parity suite).
+    overrides (interpret-mode off-TPU for the parity suite). The kernel
+    scores a chunk of more than ``PREFILL_MAX_ROWS`` query rows as
+    consecutive column pieces, each at its own ``pos`` — the same result
+    bit for bit, since every query row's softmax is independent.
     """
     if impl is None:
         impl = "kernel" if on_tpu() else "ref"
     if impl == "kernel":
-        return paged_flash_prefill(q, kp, vp, tbl, pos, start, scale=scale,
+        s, step = q.shape[1], max(1, PREFILL_MAX_ROWS // q.shape[2])
+        pieces = [(q, pos)] if s <= step else [
+            (q[:, i:i + step], pos + i) for i in range(0, s, step)]
+        out = [paged_flash_prefill(qi, kp, vp, tbl, pi, start, scale=scale,
                                    k_scale=k_scale, v_scale=v_scale,
                                    interpret=not on_tpu())
+               for qi, pi in pieces]
+        return out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)
     return ref.paged_prefill_ref(q, kp, vp, tbl, pos, start, scale,
                                  k_scale=k_scale, v_scale=v_scale)
 

@@ -6,9 +6,15 @@ byte in HBM (halving weight bandwidth — the dominant term for decode shapes)
 and dequantizes in VMEM right before the MXU: unpack nibbles → subtract the
 +8 offset → scale by the per-column f32 scale.
 
-Packing layout: byte ``[k, j]`` holds column ``2j`` (low nibble) and ``2j+1``
-(high nibble) of row ``k``; nibbles store ``int4 + 8`` with int4 ∈ [-7, 7]
-(symmetric RTN never produces -8).
+Packing layout (``kernels.ref.pack_int4``): columns are packed in groups
+of ``PACK_GROUP`` = 256; within group ``g``, byte ``[k, 128 g + j]`` holds
+column ``256 g + j`` in its low nibble and column ``256 g + 128 + j`` in
+its high nibble. A group's low nibbles thus unpack to one full 128-lane
+tile and its high nibbles to the next, so the kernel never interleaves
+lanes (an interleave needs a relayout the TPU compiler runs out of VMEM
+on). N is zero-padded up to a whole group. Nibbles store ``int4 + 8``
+with int4 ∈ [-7, 7] (symmetric RTN never produces -8); the nibble
+arithmetic runs in int32, since the TPU has no 8-bit vector shifts.
 """
 
 from __future__ import annotations
@@ -20,6 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.ref import PACK_GROUP
 from repro.kernels.ref import round_up as _rup
 
 
@@ -32,12 +39,15 @@ def _int4_matmul_kernel(x_ref, wp_ref, scale_ref, o_ref, acc_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # unpack [bk, bn//2] uint8 -> [bk, bn] f32 (interleaved low/high nibbles)
-    wp = wp_ref[...]
-    lo = (wp & 0x0F).astype(jnp.int32) - 8
-    hi = (wp >> 4).astype(jnp.int32) - 8
-    w = jnp.stack([lo, hi], axis=-1).reshape(wp.shape[0], wp.shape[1] * 2)
-    w = w.astype(jnp.float32)
+    # unpack [bk, bn//2] uint8 -> [bk, bn] f32, one 128-lane tile per half
+    # of each packing group (see the module docstring)
+    wp = wp_ref[...].astype(jnp.int32)
+    half = PACK_GROUP // 2
+    tiles = []
+    for g in range(wp.shape[1] // half):
+        byte = wp[:, g * half:(g + 1) * half]
+        tiles += [(byte & 0x0F) - 8, (byte >> 4) - 8]
+    w = jnp.concatenate(tiles, axis=1).astype(jnp.float32)
 
     acc_ref[...] += jax.lax.dot_general(
         x_ref[...].astype(jnp.float32), w,
@@ -57,14 +67,18 @@ def int4_matmul(x: jax.Array, w_packed: jax.Array, scale: jax.Array, *,
                 interpret: bool = False) -> jax.Array:
     """``y = x @ dequant(w_packed, scale)`` with in-VMEM int4 unpacking.
 
-    x [M, K], w_packed [K, N//2] uint8, scale [N]. Returns [M, N] in x.dtype.
+    x [M, K], w_packed [K, ceil(N/256)*128] uint8 (``ref.pack_int4``),
+    scale [N]. Returns [M, N] in x.dtype. ``bn`` must be a multiple of
+    ``PACK_GROUP``.
     """
+    assert bn % PACK_GROUP == 0, (bn, PACK_GROUP)
     m, kdim = x.shape
     k2, nh = w_packed.shape
-    n = nh * 2
-    assert kdim == k2
+    n = scale.shape[-1]
+    assert kdim == k2 and nh == _rup(n, PACK_GROUP) // 2, (
+        x.shape, w_packed.shape, scale.shape)
     bm_ = min(bm, _rup(m, 8))
-    bn_ = min(bn, _rup(n, 128))
+    bn_ = min(bn, _rup(n, PACK_GROUP))
     bk_ = min(bk, _rup(kdim, 128))
 
     mp, np_, kp = _rup(m, bm_), _rup(n, bn_), _rup(kdim, bk_)

@@ -19,15 +19,17 @@ from repro.kernels import dispatch as _dispatch
 from repro.kernels import ref as _ref
 from repro.kernels.ssd_scan import ssd_scan as _ssd_scan
 
-_on_tpu = _dispatch.on_tpu
 _flatten_batch = _dispatch.flatten_batch
+
+# SSD chunk length: the kernel's [L, L] intra-chunk matmul is one MXU tile
+SSD_CHUNK = 128
 
 
 def analog_matmul(x: jax.Array, w_eff: jax.Array, beta: jax.Array,
                   bound: jax.Array, *, in_bits: int = 8, out_bits: int = 8,
                   force_kernel: bool = False) -> jax.Array:
     """Fused DAC-quant → MVM → ADC-quant over arbitrary leading batch dims."""
-    if _on_tpu() or force_kernel:
+    if _dispatch.on_tpu() or force_kernel:
         return _dispatch.analog_mvm(x, w_eff, beta, bound,
                                     in_bits=in_bits, out_bits=out_bits)
     x2, lead = _flatten_batch(x)
@@ -40,26 +42,26 @@ def int4_matmul(x: jax.Array, w_packed: jax.Array, scale: jax.Array, *,
                 force_kernel: bool = False) -> jax.Array:
     """Packed-int4 weight matmul over arbitrary leading batch dims."""
     x2, lead = _flatten_batch(x)
-    if _on_tpu() or force_kernel:
+    if _dispatch.on_tpu() or force_kernel:
         from repro.kernels.int4_matmul import int4_matmul as _kernel
         m, kdim = x2.shape
-        n = w_packed.shape[-1] * 2
-        bm, bn, bk = _dispatch.select_blocks(m, kdim, n)
+        bm, bn, bk = _dispatch.select_blocks(m, kdim, scale.shape[-1])
         y = _kernel(x2, w_packed, scale, bm=bm, bn=bn, bk=bk,
-                    interpret=not _on_tpu())
+                    interpret=not _dispatch.on_tpu())
     else:
         y = _ref.int4_matmul_ref(x2, w_packed, scale)
-    return y.reshape(*lead, w_packed.shape[-1] * 2)
+    return y.reshape(*lead, scale.shape[-1])
 
 
 def ssd(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
-        c: jax.Array, *, chunk: int = 128,
+        c: jax.Array, *, chunk: int = SSD_CHUNK,
         force_kernel: bool = False) -> jax.Array:
     """Mamba-2 SSD over [B, S, H, P] inputs with [B, S, G, N] gates.
 
-    Broadcasts B/C groups to heads, flattens (B, H) and dispatches to the
-    chunked kernel (TPU) or a chunked jnp implementation mathematically
-    identical to it (CPU) — both are tested against the sequential oracle.
+    Broadcasts B/C groups to heads, flattens (B, H) and dispatches by
+    :func:`ssd_uses_kernel` to the chunked Pallas kernel or to
+    :func:`ssd_chunked_jnp`, a chunked jnp implementation of the same math
+    — both are tested against the sequential oracle.
     """
     bsz, s, h, p = x.shape
     g, n = b.shape[2], b.shape[3]
@@ -74,14 +76,25 @@ def ssd(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
     dt_f = jnp.moveaxis(dt, 2, 1).reshape(bsz * h, s)
     a_f = jnp.tile(a, bsz)
 
-    if (_on_tpu() or force_kernel) and s % chunk == 0:
+    if ssd_uses_kernel(s, chunk, force_kernel):
         y = _ssd_scan(x_f, dt_f, a_f, b_f, c_f, chunk=chunk,
-                      interpret=not _on_tpu())
+                      interpret=not _dispatch.on_tpu())
     else:
         y = ssd_chunked_jnp(x_f, dt_f, a_f, b_f, c_f,
                             chunk=min(chunk, s) if s % chunk else chunk)
     y = y.reshape(bsz, h, s, p)
     return jnp.moveaxis(y, 1, 2)
+
+
+def ssd_uses_kernel(s: int, chunk: int, force_kernel: bool = False) -> bool:
+    """The shape rule of :func:`ssd`: the Pallas kernel runs (compiled on
+    TPU, interpreted under ``force_kernel``) only when the sequence is
+    whole chunks, ``s % chunk == 0`` — long prefills and training. Any
+    other length runs :func:`ssd_chunked_jnp`, which pads to a chunk: on
+    TPU that includes every serving prefill chunk (8-16 tokens against
+    the 128-token SSD chunk), so SSM serving on the chip runs the jnp
+    path by this rule, not by a fallback."""
+    return (_dispatch.on_tpu() or force_kernel) and s % chunk == 0
 
 
 def ssd_chunked_jnp(x, dt, a, b, c, *, chunk: int = 128):

@@ -44,9 +44,16 @@ from jax.experimental.pallas import tpu as pltpu
 def _paged_decode_kernel(tbl_ref, pos_ref, start_ref, q_ref, k_ref, v_ref,
                          ks_ref, vs_ref, o_ref, m_ref, l_ref,
                          acc_scr, m_scr, l_scr, *, bs: int, nkv: int,
-                         group: int, hd: int, scale: float, nbs: int,
+                         group: int, scale: float, nbs: int,
                          quantized: bool):
-    """Tile body: online-softmax update for one (row, split, block) step."""
+    """Tile body: online-softmax update for one (row, split, block) step.
+
+    The block arrives as ``[bs * KV, hd]`` rows ordered (token, kv head),
+    so every query head scores all of them in one ``[H, hd] x [hd, bs*KV]``
+    MXU dot; columns of other kv heads are masked out of the softmax like
+    dead positions. That keeps every operand a full 2-D tile — no
+    lane-splitting reshape, which the TPU compiler refuses at hd < 128.
+    """
     b, sidx, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     nq = nkv * group
 
@@ -62,44 +69,40 @@ def _paged_decode_kernel(tbl_ref, pos_ref, start_ref, q_ref, k_ref, v_ref,
 
     @pl.when(live)
     def _compute():
-        k_blk = k_ref[0].reshape(bs, nkv, hd).astype(jnp.float32)
-        v_blk = v_ref[0].reshape(bs, nkv, hd).astype(jnp.float32)
+        k_blk = k_ref[0].astype(jnp.float32)            # [bs*KV, hd]
+        v_blk = v_ref[0].astype(jnp.float32)
         if quantized:
-            k_blk = k_blk * ks_ref[0].reshape(bs, nkv)[..., None]
-            v_blk = v_blk * vs_ref[0].reshape(bs, nkv)[..., None]
-        qg = q_ref[0].reshape(nkv, group, hd).astype(jnp.float32)
+            k_blk = k_blk * ks_ref[0]                   # [bs*KV, 1] scales
+            v_blk = v_blk * vs_ref[0]
+        q = q_ref[0].astype(jnp.float32)                # [H, hd]
 
-        # [KV, group, hd] x [KV, bs, hd] -> [KV, group, bs] (batched MXU)
-        kt = jnp.swapaxes(k_blk, 0, 1)
         logits = jax.lax.dot_general(
-            qg, kt, dimension_numbers=(((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale
+            q, k_blk, dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [H, bs*KV]
 
-        jpos = blk * bs + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bs), 2)
-        valid = (jpos >= s_b) & (jpos <= p_b)
+        col = jax.lax.broadcasted_iota(jnp.int32, (nq, bs * nkv), 1)
+        head = jax.lax.broadcasted_iota(jnp.int32, (nq, bs * nkv), 0)
+        jpos = blk * bs + col // nkv
+        valid = ((col % nkv == head // group)
+                 & (jpos >= s_b) & (jpos <= p_b))
         logits = jnp.where(valid, logits, -1e30)
 
-        m_prev = m_scr[...].reshape(nkv, group)
-        l_prev = l_scr[...].reshape(nkv, group)
-        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1))
-        p = jnp.exp(logits - m_new[..., None])
+        m_prev = m_scr[...]                              # [H, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
+        p = jnp.exp(logits - m_new)
         p = jnp.where(valid, p, 0.0)
         corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + jnp.sum(p, axis=-1)
-        vt = jnp.swapaxes(v_blk, 0, 1)          # [KV, bs, hd]
-        acc = acc_scr[...].reshape(nkv, group, hd)
-        acc_new = acc * corr[..., None] + jax.lax.dot_general(
-            p, vt, dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = m_new.reshape(1, nq)
-        l_scr[...] = l_new.reshape(1, nq)
-        acc_scr[...] = acc_new.reshape(nq, hd)
+        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
+            p, v_blk, dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)          # [H, hd]
+        m_scr[...] = m_new
 
     @pl.when(j == nbs - 1)
     def _store():
-        o_ref[0, 0] = acc_scr[...].reshape(nq * hd)
-        m_ref[0, 0] = m_scr[0]
-        l_ref[0, 0] = l_scr[0]
+        o_ref[0, 0] = acc_scr[...]
+        m_ref[0, 0] = m_scr[...]
+        l_ref[0, 0] = l_scr[...]
 
 
 @functools.partial(
@@ -123,15 +126,15 @@ def paged_flash_decode(q: jax.Array, kp: jax.Array, vp: jax.Array,
     quantized = k_scale is not None
     nbs = -(-nb // num_splits)                   # blocks per split
 
-    q2 = q.reshape(bsz, nq * hd)
-    kp2 = kp.reshape(npool, bs, nkv * hd)
-    vp2 = vp.reshape(npool, bs, nkv * hd)
+    # free HBM views: one block = bs*KV rows of hd (token-major, then head)
+    kp2 = kp.reshape(npool, bs * nkv, hd)
+    vp2 = vp.reshape(npool, bs * nkv, hd)
     if quantized:
-        ks2 = k_scale.reshape(npool, bs * nkv).astype(jnp.float32)
-        vs2 = v_scale.reshape(npool, bs * nkv).astype(jnp.float32)
+        ks2 = k_scale.reshape(npool, bs * nkv, 1).astype(jnp.float32)
+        vs2 = v_scale.reshape(npool, bs * nkv, 1).astype(jnp.float32)
     else:  # dummy 1-block operands so the kernel signature is static
-        ks2 = jnp.zeros((1, bs * nkv), jnp.float32)
-        vs2 = jnp.zeros((1, bs * nkv), jnp.float32)
+        ks2 = jnp.zeros((1, bs * nkv, 1), jnp.float32)
+        vs2 = jnp.zeros((1, bs * nkv, 1), jnp.float32)
 
     def _phys(b, s, j, tbl_ref, pos_ref, start_ref):
         # Dead steps clamp to the nearest live block: consecutive identical
@@ -140,46 +143,47 @@ def paged_flash_decode(q: jax.Array, kp: jax.Array, vp: jax.Array,
         jj = jnp.clip(blk, start_ref[b] // bs, pos_ref[b] // bs)
         return tbl_ref[b, jj]
 
+    # every block's last two dims are the array's own (TPU tiling rule)
     kv_spec = pl.BlockSpec(
-        (1, bs, nkv * hd), lambda b, s, j, *pf: (_phys(b, s, j, *pf), 0, 0))
+        (1, bs * nkv, hd), lambda b, s, j, *pf: (_phys(b, s, j, *pf), 0, 0))
     sc_spec = pl.BlockSpec(
-        (1, bs * nkv),
-        (lambda b, s, j, *pf: (_phys(b, s, j, *pf), 0)) if quantized
-        else (lambda b, s, j, *pf: (0, 0)))
+        (1, bs * nkv, 1),
+        (lambda b, s, j, *pf: (_phys(b, s, j, *pf), 0, 0)) if quantized
+        else (lambda b, s, j, *pf: (0, 0, 0)))
+
+    def part(last):      # one (row, split) partial: [H, last]
+        return pl.BlockSpec((1, 1, nq, last),
+                            lambda b, s, j, *pf: (b, s, 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(bsz, num_splits, nbs),
         in_specs=[
-            pl.BlockSpec((1, nq * hd), lambda b, s, j, *pf: (b, 0)),   # q
+            pl.BlockSpec((1, nq, hd), lambda b, s, j, *pf: (b, 0, 0)),  # q
             kv_spec, kv_spec, sc_spec, sc_spec,
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, nq * hd), lambda b, s, j, *pf: (b, s, 0)),
-            pl.BlockSpec((1, 1, nq), lambda b, s, j, *pf: (b, s, 0)),
-            pl.BlockSpec((1, 1, nq), lambda b, s, j, *pf: (b, s, 0)),
-        ],
+        out_specs=[part(hd), part(1), part(1)],
         scratch_shapes=[
             pltpu.VMEM((nq, hd), jnp.float32),       # acc
-            pltpu.VMEM((1, nq), jnp.float32),        # m
-            pltpu.VMEM((1, nq), jnp.float32),        # l
+            pltpu.VMEM((nq, 1), jnp.float32),        # m
+            pltpu.VMEM((nq, 1), jnp.float32),        # l
         ],
     )
 
     o_part, m_part, l_part = pl.pallas_call(
         functools.partial(_paged_decode_kernel, bs=bs, nkv=nkv, group=group,
-                          hd=hd, scale=scale, nbs=nbs, quantized=quantized),
+                          scale=scale, nbs=nbs, quantized=quantized),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((bsz, num_splits, nq * hd), jnp.float32),
-            jax.ShapeDtypeStruct((bsz, num_splits, nq), jnp.float32),
-            jax.ShapeDtypeStruct((bsz, num_splits, nq), jnp.float32),
+            jax.ShapeDtypeStruct((bsz, num_splits, nq, hd), jnp.float32),
+            jax.ShapeDtypeStruct((bsz, num_splits, nq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((bsz, num_splits, nq, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(tbl, pos, start, q2, kp2, vp2, ks2, vs2)
+    )(tbl, pos, start, q, kp2, vp2, ks2, vs2)
 
-    return merge_splits(o_part.reshape(bsz, num_splits, nq, hd),
-                        m_part, l_part).astype(q.dtype)
+    return merge_splits(o_part, m_part[..., 0],
+                        l_part[..., 0]).astype(q.dtype)
 
 
 def merge_splits(o_part: jax.Array, m_part: jax.Array,

@@ -63,9 +63,17 @@ from jax.experimental.pallas import tpu as pltpu
 
 def _paged_prefill_kernel(tbl_ref, pos_ref, start_ref, q_ref, k_ref, v_ref,
                           ks_ref, vs_ref, o_ref, acc_scr, m_scr, l_scr, *,
-                          bs: int, nkv: int, group: int, hd: int, s: int,
+                          bs: int, nkv: int, group: int, s: int,
                           scale: float, nb: int, quantized: bool):
-    """Tile body: online-softmax update for one (row, block) step."""
+    """Tile body: online-softmax update for one (row, block) step.
+
+    The chunk's queries arrive as ``[S * H, hd]`` rows ordered (column,
+    head) and the block as ``[bs * KV, hd]`` rows ordered (token, kv
+    head): one MXU dot scores every query row against every block row,
+    and the mask drops pairs whose kv head does not serve the query head,
+    beside the causal window. Every operand stays a full 2-D tile — no
+    lane-splitting reshape, which the TPU compiler refuses at hd < 128.
+    """
     b, j = pl.program_id(0), pl.program_id(1)
     nq = nkv * group
 
@@ -80,55 +88,41 @@ def _paged_prefill_kernel(tbl_ref, pos_ref, start_ref, q_ref, k_ref, v_ref,
 
     @pl.when(live)
     def _compute():
-        k_blk = k_ref[0].reshape(bs, nkv, hd).astype(jnp.float32)
-        v_blk = v_ref[0].reshape(bs, nkv, hd).astype(jnp.float32)
+        k_blk = k_ref[0].astype(jnp.float32)            # [bs*KV, hd]
+        v_blk = v_ref[0].astype(jnp.float32)
         if quantized:
-            k_blk = k_blk * ks_ref[0].reshape(bs, nkv)[..., None]
-            v_blk = v_blk * vs_ref[0].reshape(bs, nkv)[..., None]
-        # chunk queries, GQA-grouped with the kv-head dim leading so the
-        # MXU sees one batched [S*group, hd] x [hd, bs] dot per kv head
-        qg = jnp.swapaxes(
-            q_ref[0].reshape(s, nkv, group, hd), 0, 1
-        ).reshape(nkv, s * group, hd).astype(jnp.float32)
+            k_blk = k_blk * ks_ref[0]                   # [bs*KV, 1] scales
+            v_blk = v_blk * vs_ref[0]
+        q = q_ref[0].astype(jnp.float32)                # [S*H, hd]
 
-        kt = jnp.swapaxes(k_blk, 0, 1)          # [KV, bs, hd]
         logits = jax.lax.dot_general(
-            qg, kt, dimension_numbers=(((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale
-        logits = logits.reshape(nkv, s, group, bs)
+            q, k_blk, dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [S*H, bs*KV]
 
         # causal window against the per-row cursors: query column i (at
         # logical pos p_b + i) sees KV positions start <= jpos <= p_b + i
-        jpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, bs), 3)
-        qpos = p_b + jax.lax.broadcasted_iota(jnp.int32, (1, s, 1, 1), 1)
-        valid = (jpos >= s_b) & (jpos <= qpos)
+        col = jax.lax.broadcasted_iota(jnp.int32, (s * nq, bs * nkv), 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (s * nq, bs * nkv), 0)
+        jpos = j * bs + col // nkv
+        qpos = p_b + row // nq
+        valid = ((col % nkv == (row % nq) // group)
+                 & (jpos >= s_b) & (jpos <= qpos))
         logits = jnp.where(valid, logits, -1e30)
 
-        m_prev = m_scr[...].reshape(nkv, s, group)
-        l_prev = l_scr[...].reshape(nkv, s, group)
-        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1))
-        p = jnp.exp(logits - m_new[..., None])
+        m_prev = m_scr[...]                              # [S*H, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
+        p = jnp.exp(logits - m_new)
         p = jnp.where(valid, p, 0.0)
         corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + jnp.sum(p, axis=-1)
-        vt = jnp.swapaxes(v_blk, 0, 1)          # [KV, bs, hd]
-        acc = acc_scr[...].reshape(nkv, s, group, hd)
-        pv = jax.lax.dot_general(
-            p.reshape(nkv, s * group, bs), vt,
-            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32).reshape(nkv, s, group, hd)
-        acc_new = acc * corr[..., None] + pv
-        m_scr[...] = m_new.reshape(1, s * nq)
-        l_scr[...] = l_new.reshape(1, s * nq)
-        acc_scr[...] = acc_new.reshape(s * nq, hd)
+        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
+            p, v_blk, dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)          # [S*H, hd]
+        m_scr[...] = m_new
 
     @pl.when(j == nb - 1)
     def _store():
-        acc = acc_scr[...].reshape(nkv, s, group, hd)
-        l = l_scr[...].reshape(nkv, s, group)
-        out = acc / jnp.maximum(l, 1e-30)[..., None]
-        # back to the [S, H, hd] head order of the q operand
-        o_ref[0] = jnp.swapaxes(out, 0, 1).reshape(s * nq * hd)
+        o_ref[0] = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -150,15 +144,16 @@ def paged_flash_prefill(q: jax.Array, kp: jax.Array, vp: jax.Array,
     group = nq // nkv
     quantized = k_scale is not None
 
-    q2 = q.reshape(bsz, s * nq * hd)
-    kp2 = kp.reshape(npool, bs, nkv * hd)
-    vp2 = vp.reshape(npool, bs, nkv * hd)
+    # free HBM views: rows (column, head) of q, (token, kv head) of a block
+    q2 = q.reshape(bsz, s * nq, hd)
+    kp2 = kp.reshape(npool, bs * nkv, hd)
+    vp2 = vp.reshape(npool, bs * nkv, hd)
     if quantized:
-        ks2 = k_scale.reshape(npool, bs * nkv).astype(jnp.float32)
-        vs2 = v_scale.reshape(npool, bs * nkv).astype(jnp.float32)
+        ks2 = k_scale.reshape(npool, bs * nkv, 1).astype(jnp.float32)
+        vs2 = v_scale.reshape(npool, bs * nkv, 1).astype(jnp.float32)
     else:  # dummy 1-block operands so the kernel signature is static
-        ks2 = jnp.zeros((1, bs * nkv), jnp.float32)
-        vs2 = jnp.zeros((1, bs * nkv), jnp.float32)
+        ks2 = jnp.zeros((1, bs * nkv, 1), jnp.float32)
+        vs2 = jnp.zeros((1, bs * nkv, 1), jnp.float32)
 
     def _phys(b, j, tbl_ref, pos_ref, start_ref):
         # Dead steps clamp to the nearest live block: consecutive identical
@@ -166,36 +161,32 @@ def paged_flash_prefill(q: jax.Array, kp: jax.Array, vp: jax.Array,
         jj = jnp.clip(j, start_ref[b] // bs, (pos_ref[b] + s - 1) // bs)
         return tbl_ref[b, jj]
 
+    # every block's last two dims are the array's own (TPU tiling rule)
     kv_spec = pl.BlockSpec(
-        (1, bs, nkv * hd), lambda b, j, *pf: (_phys(b, j, *pf), 0, 0))
+        (1, bs * nkv, hd), lambda b, j, *pf: (_phys(b, j, *pf), 0, 0))
     sc_spec = pl.BlockSpec(
-        (1, bs * nkv),
-        (lambda b, j, *pf: (_phys(b, j, *pf), 0)) if quantized
-        else (lambda b, j, *pf: (0, 0)))
+        (1, bs * nkv, 1),
+        (lambda b, j, *pf: (_phys(b, j, *pf), 0, 0)) if quantized
+        else (lambda b, j, *pf: (0, 0, 0)))
+    row_spec = pl.BlockSpec((1, s * nq, hd), lambda b, j, *pf: (b, 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(bsz, nb),
-        in_specs=[
-            pl.BlockSpec((1, s * nq * hd), lambda b, j, *pf: (b, 0)),   # q
-            kv_spec, kv_spec, sc_spec, sc_spec,
-        ],
-        out_specs=[
-            pl.BlockSpec((1, s * nq * hd), lambda b, j, *pf: (b, 0)),
-        ],
+        in_specs=[row_spec, kv_spec, kv_spec, sc_spec, sc_spec],
+        out_specs=[row_spec],
         scratch_shapes=[
             pltpu.VMEM((s * nq, hd), jnp.float32),       # acc
-            pltpu.VMEM((1, s * nq), jnp.float32),        # m
-            pltpu.VMEM((1, s * nq), jnp.float32),        # l
+            pltpu.VMEM((s * nq, 1), jnp.float32),        # m
+            pltpu.VMEM((s * nq, 1), jnp.float32),        # l
         ],
     )
 
     (o,) = pl.pallas_call(
         functools.partial(_paged_prefill_kernel, bs=bs, nkv=nkv, group=group,
-                          hd=hd, s=s, scale=scale, nb=nb,
-                          quantized=quantized),
+                          s=s, scale=scale, nb=nb, quantized=quantized),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((bsz, s * nq * hd), jnp.float32)],
+        out_shape=[jax.ShapeDtypeStruct((bsz, s * nq, hd), jnp.float32)],
         interpret=interpret,
     )(tbl, pos, start, q2, kp2, vp2, ks2, vs2)
 

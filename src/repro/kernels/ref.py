@@ -26,6 +26,10 @@ def _qmax(bits: int) -> float:
 # less than 2^-16 relative — true for any reassociation of an f32 dot.
 ADC_TIE_BREAK = 1.0 - 2.0 ** -16
 
+# Columns per packing group of the int4 layout (see :func:`pack_int4`):
+# two 128-lane tiles, one per nibble.
+PACK_GROUP = 256
+
 
 def round_up(v: int, mult: int) -> int:
     """Round ``v`` up to a multiple of ``mult`` (block/tile padding helper)."""
@@ -83,25 +87,38 @@ def int4_matmul_ref(x: jax.Array, w_packed: jax.Array, scale: jax.Array
                     ) -> jax.Array:
     """Oracle for the packed-int4 digital deployment matmul.
 
-    w_packed [K, N//2] uint8 — byte j holds column 2j in the low nibble and
-    column 2j+1 in the high nibble, each an unsigned nibble storing
-    ``int4 + 8`` (int4 ∈ [-7, 7] from symmetric RTN).
-    scale    [N] per-output-channel dequant scales.
+    w_packed [K, ceil(N/256)*128] uint8 in the :func:`pack_int4` layout.
+    scale    [N] per-output-channel dequant scales (N sets the width).
     """
-    lo = (w_packed & 0x0F).astype(jnp.int32) - 8
-    hi = (w_packed >> 4).astype(jnp.int32) - 8
-    w = jnp.stack([lo, hi], axis=-1).reshape(w_packed.shape[0], -1)
+    w = unpack_int4(w_packed)[:, :scale.shape[-1]]
     w = w.astype(jnp.float32) * scale.astype(jnp.float32)[None, :]
     y = jnp.matmul(x.astype(jnp.float32), w, preferred_element_type=jnp.float32)
     return y.astype(x.dtype)
 
 
 def pack_int4(w_int: jax.Array) -> jax.Array:
-    """Pack int8-carrier int4 values ([-7,7], [K, N] with N even) to [K, N//2]."""
-    u = (w_int.astype(jnp.int32) + 8).astype(jnp.uint8)
-    lo = u[:, 0::2]
-    hi = u[:, 1::2]
-    return (lo | (hi << 4)).astype(jnp.uint8)
+    """Pack int8-carrier int4 values ([-7,7], [K, N]) to [K, ceil(N/256)*128].
+
+    Columns go in groups of :data:`PACK_GROUP` = 256 (N is zero-padded to
+    a whole group). In group ``g``, byte ``128 g + j`` holds column
+    ``256 g + j`` in its low nibble and column ``256 g + 128 + j`` in its
+    high nibble, each stored as ``int4 + 8``: the low nibbles of a group
+    unpack to one whole 128-lane tile and the high nibbles to the next,
+    so the kernel unpacks without interleaving lanes.
+    """
+    k, n = w_int.shape
+    npad = round_up(n, PACK_GROUP) - n
+    u = (jnp.pad(w_int.astype(jnp.int32), ((0, 0), (0, npad))) + 8
+         ).astype(jnp.uint8).reshape(k, -1, 2, PACK_GROUP // 2)
+    return (u[:, :, 0] | (u[:, :, 1] << 4)).reshape(k, -1)
+
+
+def unpack_int4(w_packed: jax.Array) -> jax.Array:
+    """Inverse of :func:`pack_int4`: [K, G*128] uint8 → [K, G*256] int32
+    (padded columns included; callers slice to N)."""
+    k = w_packed.shape[0]
+    u = w_packed.astype(jnp.int32).reshape(k, -1, 1, PACK_GROUP // 2)
+    return (jnp.concatenate([u & 0x0F, u >> 4], axis=2) - 8).reshape(k, -1)
 
 
 def paged_decode_ref(q: jax.Array, kp: jax.Array, vp: jax.Array,

@@ -15,7 +15,8 @@ TPU-idiomatic substitute for persistent-CTA state.
 
 Shapes (heads pre-flattened, B/C pre-broadcast from groups to heads):
     x [BH, S, P], dt [BH, S], a [BH] (negative), b/c [BH, S, N]
-Grid: (BH, S/L); scratch state [N, P] f32, reset at chunk 0.
+Grid: (BH, S/L); scratch state [N, P] f32, reset at chunk 0. The kernel
+runs at ``S % L == 0`` (``kernels.ops.ssd`` chooses it by shape).
 """
 
 from __future__ import annotations
@@ -28,9 +29,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref,
+def _ssd_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, state_ref,
                 *, chunk: int):
-    """Pallas body: chunked SSD recurrence for one (batch·head) block."""
+    """Pallas body: chunked SSD recurrence for one (batch·head) block.
+
+    ``dt`` arrives as a ``[1, L]`` row; its column form is a transpose.
+    Mosaic has no cumsum, so the inclusive prefix sum is a dot against
+    an upper-triangular ones matrix at full f32 precision.
+    """
     c_idx = pl.program_id(1)
 
     @pl.when(c_idx == 0)
@@ -38,44 +44,49 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref,
         state_ref[...] = jnp.zeros_like(state_ref)
 
     x = x_ref[0].astype(jnp.float32)           # [L, P]
-    dt = dt_ref[0].astype(jnp.float32)         # [L]
-    a = a_ref[0].astype(jnp.float32)           # scalar
+    dt_row = dt_ref[0].astype(jnp.float32)     # [1, L]
+    a = a_ref[pl.program_id(0)]                # scalar (SMEM)
     bmat = b_ref[0].astype(jnp.float32)        # [L, N]
     cmat = c_ref[0].astype(jnp.float32)        # [L, N]
 
-    la = dt * a                                # log-decay per step  [L]
-    cums = jnp.cumsum(la)                      # inclusive cumulative [L]
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    la = dt_row * a                            # log-decay per step  [1, L]
+    cums = jnp.dot(la, (row <= col).astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)  # inclusive [1, L]
+    cums_col = cums.T                          # [L, 1]
+    dt_col = dt_row.T                          # [L, 1]
 
     # --- intra-chunk: (C Bᵀ ⊙ M) (dt ⊙ X) on the MXU -----------------------
     # M[t, r] = exp(cums[t] - cums[r]) for r <= t: x_r enters the state at
     # step r *after* that step's decay a_r was applied to h_{r-1}, so its
     # decay to step t spans (r, t] only.
-    rel = cums[:, None] - cums[None, :]
-    mask = jnp.tril(jnp.ones((chunk, chunk), jnp.float32))
+    rel = cums_col - cums                      # [L(t), L(r)]
     # clamp before exp: above-diagonal rel is positive and can overflow to
     # inf, and inf * mask(0) = NaN (valid entries always have rel <= 0)
-    decay = jnp.exp(jnp.minimum(rel, 0.0)) * mask
+    decay = jnp.where(col <= row, jnp.exp(jnp.minimum(rel, 0.0)), 0.0)
     gates = jax.lax.dot_general(cmat, bmat,
                                 (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # [L, L]
-    y_intra = jax.lax.dot_general(gates * decay, dt[:, None] * x,
+    y_intra = jax.lax.dot_general(gates * decay, dt_col * x,
                                   (((1,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
 
     # --- inter-chunk: contribution of the carried state --------------------
     h_in = state_ref[...]                      # [N, P]
-    y_inter = jnp.exp(cums)[:, None] * jax.lax.dot_general(
+    y_inter = jnp.exp(cums_col) * jax.lax.dot_general(
         cmat, h_in, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
     y_ref[0] = (y_intra + y_inter).astype(y_ref.dtype)
 
     # --- state update -------------------------------------------------------
-    total = cums[-1]
-    w_r = jnp.exp(total - cums) * dt           # decay from r to chunk end [L]
+    total = jnp.sum(la, axis=1, keepdims=True)  # [1, 1]
+    w_r = jnp.exp(total - cums_col) * dt_col   # decay from r to chunk end [L, 1]
     state_ref[...] = (jnp.exp(total) * h_in +
-                      jax.lax.dot_general(bmat * w_r[:, None], x,
-                                          (((0,), (0,)), ((), ())),
+                      jax.lax.dot_general((bmat * w_r).T, x,
+                                          (((1,), (0,)), ((), ())),
                                           preferred_element_type=jnp.float32))
 
 
@@ -89,13 +100,15 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
     assert s % chunk == 0, f"seq {s} must be a multiple of chunk {chunk}"
     grid = (bh, s // chunk)
 
+    # blocks keep their last two dims aligned or full (TPU tiling rule):
+    # dt goes in as [BH, 1, S] rows, the per-head decay rate `a` in SMEM
     return pl.pallas_call(
         functools.partial(_ssd_kernel, chunk=chunk),
         grid=grid,
         in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),                 # a
             pl.BlockSpec((1, chunk, p), lambda i, j: (i, j, 0)),   # x
-            pl.BlockSpec((1, chunk), lambda i, j: (i, j)),         # dt
-            pl.BlockSpec((1,), lambda i, j: (i,)),                 # a
+            pl.BlockSpec((1, 1, chunk), lambda i, j: (i, 0, j)),   # dt
             pl.BlockSpec((1, chunk, n), lambda i, j: (i, j, 0)),   # b
             pl.BlockSpec((1, chunk, n), lambda i, j: (i, j, 0)),   # c
         ],
@@ -103,4 +116,4 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
         out_shape=jax.ShapeDtypeStruct((bh, s, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
         interpret=interpret,
-    )(x, dt, a, b, c)
+    )(a.astype(jnp.float32), x, dt.reshape(bh, 1, s), b, c)

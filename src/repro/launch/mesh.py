@@ -7,6 +7,15 @@ touches jax device state — required by the dry-run contract.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes. The sharding rules of
+    ``distributed.sharding`` are GSPMD hints (``with_sharding_constraint``),
+    which only Auto mesh axes take — and ``jax.make_mesh`` defaults to
+    Explicit axes."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -19,7 +28,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
@@ -27,4 +36,4 @@ def make_host_mesh(model: int = 1):
     used by tests (e.g. 8 forced host devices) and the CPU examples."""
     n = len(jax.devices())
     assert n % model == 0
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return auto_mesh((n // model, model), ("data", "model"))

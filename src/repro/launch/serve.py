@@ -50,6 +50,7 @@ from repro.core import devices as devices_lib
 from repro.core.analog import (AnalogConfig, pack_int4_weights,
                                perturb_analog_weights)
 from repro.core.noise import validate_noise_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build
 from repro.serve.decode import digital_int4_config, generate
 from repro.serve.frontend import AsyncServeFrontend, ShedError
@@ -376,6 +377,7 @@ def main():
     args = ap.parse_args()
     # honest config: reject meaningless noise settings before any work
     validate_noise_config(args.noise_model, args.noise_gamma)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -471,8 +473,9 @@ def main():
         # watchdog cadence scaled to the workload so short demo runs
         # still health-check a handful of times
         recal_interval=max(1, est_steps // 8) if drift_dt else 25,
-        # open-loop modes bound the queue and survive step faults —
-        # a public front door must degrade, not die
+        # open-loop modes bound the queue and survive step faults — a
+        # public front door must degrade, not die (a finite --qps replay
+        # still exits non-zero on any fault, below)
         max_queue=args.max_queue if open_loop else 0,
         fault_tolerant=open_loop, tp=args.tp))
     # honest feature reporting: a requested-but-inert feature warns
@@ -534,6 +537,13 @@ def main():
               f"{len(reqs)} arrivals): {total} tokens in {wall:.2f}s "
               f"({total / wall:.1f} tok/s), outcomes {by}, "
               f"{fe.steps} engine steps; {lifecycle_report(eng)}")
+        # a finite replay is a run to completion, not a front door: any
+        # step fault or errored request fails the process
+        if eng.fault_count or by.get("errored"):
+            first = next(iter(eng.errors.values()), "")
+            sys.exit(f"[serve] open-loop replay failed: {eng.fault_count} "
+                     f"step faults, {by.get('errored', 0)} errored "
+                     f"requests; first error: {first}")
         return
 
     t0 = time.perf_counter()

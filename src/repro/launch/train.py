@@ -24,6 +24,7 @@ from repro.core.analog import AnalogConfig
 from repro.data.corpus import MarkovCorpus
 from repro.data.synthetic import GenConfig, generate_synthetic
 from repro.distributed import sharding as shd
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import build
 from repro.train.recipes import distill_recipe, pretrain_recipe
@@ -50,6 +51,7 @@ def main():
     ap.add_argument("--grad-compression", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -394,18 +394,13 @@ class _Slot:
 # The cache pytree is donated (the engine rebinds self.caches with the
 # result immediately, so the input buffers are dead): the slot caches are
 # updated in place instead of copied every decode block / mixed step.
-# CPU ignores donation, so skip it there to keep tests warning-free.
 # ---------------------------------------------------------------------------
-
-def _donate(*argnums):
-    """donate_argnums for jax.jit, disabled on CPU (donation unsupported)."""
-    return () if jax.default_backend() == "cpu" else argnums
 
 
 @functools.partial(jax.jit,
                    static_argnames=("cfg", "paged", "kv_bits", "cow",
                                     "snaps", "restore"),
-                   donate_argnums=_donate(0))
+                   donate_argnums=(0,))
 def _admit_jit(caches, slot, start, pos0, tbl_row, wtbl_row, cow_src,
                cow_dst, snap_src, *, cfg, paged=False, kv_bits=0,
                cow=False, snaps=False, restore=False):
@@ -473,7 +468,7 @@ def _admit_jit(caches, slot, start, pos0, tbl_row, wtbl_row, cow_src,
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "paged", "kv_bits"),
-                   donate_argnums=_donate(0))
+                   donate_argnums=(0,))
 def _snap_jit(caches, slot, snap_dst, *, cfg, paged=False, kv_bits=0):
     """Capture slot ``slot``'s SSM/conv state rows into snapshot slot
     ``snap_dst`` — one device copy per mamba state leaf, taken at a
@@ -537,7 +532,7 @@ def _decode_scan(params, caches, toks, off, active, keys, counts, temp,
 
 @functools.partial(jax.jit, static_argnames=("cfg", "acfg", "use_top_k",
                                              "use_top_p", "k", "mesh"),
-                   donate_argnums=_donate(1))
+                   donate_argnums=(1,))
 def _step_jit(params, caches, toks, off, active, keys, counts, temp, topk,
               topp, gfirst, *, cfg, acfg, use_top_k, use_top_p, k,
               mesh=None):
@@ -584,7 +579,7 @@ def _scatter_rows(caches, sub, idx, axes):
 @functools.partial(jax.jit, static_argnames=("cfg", "acfg", "use_top_k",
                                              "use_top_p", "k", "paged",
                                              "snaps", "mesh"),
-                   donate_argnums=_donate(1))
+                   donate_argnums=(1,))
 def _mixed_step_jit(params, caches, toks, off, active, keys, counts, temp,
                     topk, topp, gfirst, pf_idx, pf_toks, pf_mask, pf_off, *,
                     cfg, acfg, use_top_k, use_top_p, k, paged,
@@ -702,7 +697,7 @@ def _verify_and_commit(params, caches, toks, drafts, off, active, keys,
                    static_argnames=("cfg", "acfg", "dcfg", "dacfg",
                                     "use_top_k", "use_top_p", "k", "paged",
                                     "snaps", "mesh"),
-                   donate_argnums=_donate(2, 3))
+                   donate_argnums=(2, 3))
 def _spec_step_jit(params, draft_params, caches, draft_caches, toks, off,
                    active, keys, counts, temp, topk, topp, gfirst, *, cfg,
                    acfg, dcfg, dacfg, use_top_k, use_top_p, k, paged,
@@ -748,7 +743,7 @@ def _spec_step_jit(params, draft_params, caches, draft_caches, toks, off,
 @functools.partial(jax.jit, static_argnames=("cfg", "acfg", "use_top_k",
                                              "use_top_p", "paged", "snaps",
                                              "mesh"),
-                   donate_argnums=_donate(1))
+                   donate_argnums=(1,))
 def _spec_verify_jit(params, caches, toks, off, active, keys, counts, temp,
                      topk, topp, gfirst, drafts, *, cfg, acfg, use_top_k,
                      use_top_p, paged, snaps=False, mesh=None):
@@ -768,7 +763,7 @@ def _spec_verify_jit(params, caches, toks, off, active, keys, counts, temp,
 
 
 @functools.partial(jax.jit, static_argnames=("dcfg", "dacfg", "mesh"),
-                   donate_argnums=_donate(1))
+                   donate_argnums=(1,))
 def _draft_step_jit(draft_params, draft_caches, toks, off, active, *,
                     dcfg, dacfg, mesh=None):
     """Advance the model drafter's cache by the one decode token a mixed
@@ -786,7 +781,7 @@ def _draft_step_jit(draft_params, draft_caches, toks, off, active, *,
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "acfg", "mesh"),
-                   donate_argnums=_donate(1))
+                   donate_argnums=(1,))
 def _draft_prefill_jit(params, caches, slot, toks, mask, npad, *, cfg,
                        acfg, mesh=None):
     """Reset draft-cache slot ``slot`` and prefill the full padded prompt
@@ -905,7 +900,8 @@ class ServeEngine:
         # the drafter can gate its packed-int4 path on it.
         self.mesh = None
         if scfg.tp > 1:
-            reason = sharding.serve_tp_unsupported(cfg, acfg, scfg.tp)
+            reason = sharding.serve_tp_unsupported(
+                cfg, acfg, scfg.tp, paged=paged, chunk=scfg.prefill_chunk)
             if reason is not None:
                 self.gating_reasons["tensor_parallel"] = reason
             else:

@@ -34,18 +34,3 @@ def assert_adc_parity(y1, y0, lsb, *, max_flip_rate=1e-4):
 def pytest_configure(config):
     config.addinivalue_line(
         "markers", "slow: long-running integration tests (subprocess pjit)")
-
-
-def make_abstract_mesh(axis_sizes, axis_names):
-    """Version-tolerant ``jax.sharding.AbstractMesh`` constructor.
-
-    The positional ``AbstractMesh((1, 2), ("data", "model"))`` form was
-    removed; depending on the jax release the constructor takes either a
-    tuple of ``(name, size)`` pairs (0.4.x) or separate
-    ``(axis_sizes, axis_names)`` tuples (0.5+). Try both.
-    """
-    mesh_cls = jax.sharding.AbstractMesh
-    try:
-        return mesh_cls(tuple(zip(axis_names, axis_sizes)))
-    except TypeError:
-        return mesh_cls(tuple(axis_sizes), tuple(axis_names))

@@ -255,7 +255,7 @@ def test_pack_int4_weights_serving_parity():
     site = packed["blocks"]["attn"]["o"]
     kshape = site["kernel"].shape
     assert site["int4"]["packed"].shape == (
-        kshape[0], kshape[1], kshape[2] // 2)
+        kshape[0], kshape[1], ref.round_up(kshape[2], ref.PACK_GROUP) // 2)
     assert site["int4"]["packed"].dtype == jnp.uint8
     toks = jax.random.randint(key, (2, 12), 0, cfg.vocab_size)
     acfg = AnalogConfig(mode="rtn", use_pallas=True, int4_serve=True)

@@ -71,7 +71,7 @@ def test_int4_matmul_vs_oracle(m, k, n):
     w_int, scale = rtn_quantize(w, 4)
     wp = pack_int4(w_int)
     ref = int4_matmul_ref(x, wp, scale[0])
-    ker = int4_matmul(x, wp, scale[0], bm=64, bn=128, bk=128, interpret=True)
+    ker = int4_matmul(x, wp, scale[0], bm=64, bn=256, bk=128, interpret=True)
     np.testing.assert_allclose(np.asarray(ker), np.asarray(ref), rtol=1e-5,
                                atol=1e-5)
     # and the packed path equals dense dequant matmul exactly

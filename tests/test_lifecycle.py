@@ -514,3 +514,30 @@ def test_frontend_closed_loop_parity():
     for res in asyncio.run(scenario()):
         assert res.status == "finished"
         np.testing.assert_array_equal(res.tokens, ref[res.uid])
+
+
+@pytest.mark.parametrize("fault", [False, True])
+def test_finite_qps_replay_exits_nonzero_on_step_fault(monkeypatch, fault):
+    """``launch.serve --qps`` survives step faults while it serves (the
+    frontend's contract) but a finite replay with any fault or errored
+    request fails the process; a clean replay returns normally."""
+    import sys
+
+    from repro.launch import serve as serve_mod
+
+    if fault:
+        def boom(self):
+            raise RuntimeError("injected dispatch fault")
+        monkeypatch.setattr(ServeEngine, "_dispatch", boom)
+    monkeypatch.setattr(serve_mod, "enable_compile_cache", lambda: "")
+    monkeypatch.setattr(sys, "argv", [
+        "serve", "--arch", "llama-3.2-1b", "--reduced", "--paged",
+        "--qps", "1000", "--num-requests", "2", "--new-tokens", "2",
+        "--prompt-len", "4"])
+    if fault:
+        with pytest.raises(SystemExit) as e:
+            serve_mod.main()
+        assert e.value.code not in (0, None)
+        assert "injected dispatch fault" in str(e.value.code)
+    else:
+        serve_mod.main()

@@ -240,6 +240,23 @@ def test_prefill_dispatch_routing():
                                atol=2e-6, rtol=2e-6)
 
 
+@pytest.mark.parametrize("max_rows", [8, 24])    # pieces of 1 and 3 columns
+def test_prefill_dispatch_splits_long_chunks_bitwise(monkeypatch, max_rows):
+    """A chunk of more query rows than one kernel call may hold in VMEM
+    is scored in column pieces at shifted cursors — bitwise the unsplit
+    kernel."""
+    bsz, s, nq, nkv, hd, bs, nb = 3, 5, 8, 2, 16, 4, 6
+    q, kp, vp, tbl, pos, start = _setup_prefill(17, bsz, s, nq, nkv, hd,
+                                                bs, nb)
+    scale = hd ** -0.5
+    whole = paged_flash_prefill(q, kp, vp, tbl, pos, start, scale=scale,
+                                interpret=True)
+    monkeypatch.setattr(dispatch, "PREFILL_MAX_ROWS", max_rows)
+    split = dispatch.paged_prefill_attention(q, kp, vp, tbl, pos, start,
+                                             scale, impl="kernel")
+    np.testing.assert_array_equal(np.asarray(split), np.asarray(whole))
+
+
 def test_prefill_last_column_matches_decode():
     """Seam consistency: a chunk's last column must score exactly like a
     decode step at the same cursor (same pool, pos' = pos + S - 1)."""

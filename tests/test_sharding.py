@@ -15,16 +15,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from conftest import make_abstract_mesh
+from jax.sharding import AbstractMesh
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config
 from repro.distributed import sharding as shd
-from repro.launch.mesh import make_host_mesh
+from repro.launch.mesh import auto_mesh
 
 
 def _mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return auto_mesh((1, 1), ("data", "model"))
 
 
 def test_param_specs_sites():
@@ -56,7 +56,7 @@ def test_param_specs_sites():
 
 
 def test_divisibility_guard_drops_axes():
-    mesh = make_abstract_mesh((1, 2), ("data", "model"))
+    mesh = AbstractMesh((1, 2), ("data", "model"))
     rules = shd.default_rules(mesh)
     with shd.activate(mesh, rules):
         # 7 not divisible by model=2 → replicated
@@ -67,7 +67,7 @@ def test_divisibility_guard_drops_axes():
 
 
 def test_zero_spec_upgrades_free_dim():
-    mesh = make_abstract_mesh((2, 1), ("data", "model"))
+    mesh = AbstractMesh((2, 1), ("data", "model"))
     rules = shd.default_rules(mesh)
     params = {"w": jnp.zeros((8, 6))}
     with shd.activate(mesh, rules):
@@ -86,7 +86,7 @@ def test_serve_param_specs_column_parallel():
     on "model" (column-parallel — no FP contraction ever spans shards);
     the router, input ranges, and the embedding table replicate; MoE
     experts shard on the expert dim."""
-    mesh = make_abstract_mesh((1, 2), ("data", "model"))
+    mesh = AbstractMesh((1, 2), ("data", "model"))
     params = {
         "blocks": {
             "attn": {"qkv": {"kernel": jnp.zeros((4, 8, 16)),
@@ -126,7 +126,7 @@ def test_serve_param_specs_moe_real_config():
     cfg = get_config("dbrx-132b").reduce()
     cfg = dc.replace(cfg, capacity_factor=float(cfg.num_experts))
     cfg, params, labels = build(cfg, jax.random.PRNGKey(0))
-    mesh = make_abstract_mesh((1, 2), ("data", "model"))
+    mesh = AbstractMesh((1, 2), ("data", "model"))
     with shd.activate(mesh, shd.serve_rules(mesh)):
         specs = shd.param_spec_tree(params)
     flat = jax.tree_util.tree_flatten_with_path(specs)[0]
@@ -143,7 +143,7 @@ def test_cache_spec_tree_paged_and_snapshot_layouts():
     block tables, cursors, and snapshot pools replicate. Under serve
     rules SSM/conv state replicates (mamba computes replicated); under
     training rules it shards heads/channels."""
-    mesh = make_abstract_mesh((1, 2), ("data", "model"))
+    mesh = AbstractMesh((1, 2), ("data", "model"))
     caches = {
         "kp": jnp.zeros((8, 16, 4, 16), jnp.int8),   # pool,bs,KV,hd
         "vp": jnp.zeros((8, 16, 3, 16), jnp.int8),   # KV=3: not divisible
@@ -194,7 +194,8 @@ _SUBPROCESS_PROG = textwrap.dedent("""
                                         make_train_step)
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    from repro.launch.mesh import auto_mesh
+    mesh = auto_mesh((4, 2), ("data", "model"))
     rules = shd.default_rules(mesh)
     cfg = get_config("granite-3-8b").reduce()
     key = jax.random.PRNGKey(0)
@@ -220,7 +221,7 @@ _SUBPROCESS_PROG = textwrap.dedent("""
             losses.append(float(m["loss"]))
 
     # elastic: shrink data axis 4 -> 2 (device loss), values must be intact
-    small = jax.make_mesh((2, 2), ("data", "model"))
+    small = auto_mesh((2, 2), ("data", "model"))
     before = [np.asarray(x) for x in jax.tree.leaves(params)]
     params2 = reshard(params, small)
     after = [np.asarray(x) for x in jax.tree.leaves(params2)]

@@ -232,7 +232,34 @@ def test_tp_gating_pallas_refused():
         # reason check only needs the API, not real devices
         import unittest.mock as mock
         with mock.patch.object(jax, "devices", lambda *a: [devs[0]] * 8):
-            reason = shd.serve_tp_unsupported(cfg, acfg, 2)
+            reason = shd.serve_tp_unsupported(cfg, acfg, 2, paged=True,
+                                             chunk=8)
     else:
-        reason = shd.serve_tp_unsupported(cfg, acfg, 2)
+        reason = shd.serve_tp_unsupported(cfg, acfg, 2, paged=True, chunk=8)
     assert reason is not None and "allas" in reason
+
+
+@pytest.mark.parametrize("arch,paged,chunk,refused", [
+    ("granite-3-8b", True, 8, True),      # paged flash kernels
+    ("granite-3-8b", False, 8, False),    # contiguous cache: jnp attention
+    ("mamba2-130m", False, 128, True),    # whole SSD chunks: ssd_scan
+    ("mamba2-130m", False, 8, False),     # ragged chunks: jnp SSD
+])
+def test_tp_gate_on_tpu_refuses_only_pallas_routes(arch, paged, chunk,
+                                                   refused):
+    """On TPU the gate refuses exactly the routes that reach a
+    pallas_call (paged attention, whole-chunk SSD prefill) and lets the
+    contiguous-cache jnp routes shard."""
+    import unittest.mock as mock
+
+    import repro.distributed.sharding as shd
+    from repro.kernels import dispatch
+    cfg = get_config(arch).reduce()
+    dev = jax.devices()[0]
+    with mock.patch.object(jax, "devices", lambda *a: [dev] * 8), \
+            mock.patch.object(dispatch, "on_tpu", lambda: True):
+        reason = shd.serve_tp_unsupported(cfg, AnalogConfig(mode="off"), 2,
+                                          paged=paged, chunk=chunk)
+    assert (reason is not None) == refused, reason
+    if refused:
+        assert "Pallas" in reason

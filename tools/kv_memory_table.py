@@ -88,18 +88,6 @@ def _gib(n: int) -> str:
     return f"{n / 2**30:.1f}"
 
 
-def _abstract_mesh(axis_sizes, axis_names):
-    """Version-tolerant ``jax.sharding.AbstractMesh`` constructor (the
-    positional form changed across jax releases; mirror of the tests
-    helper so this tool needs no devices to resolve specs)."""
-    import jax
-    mesh_cls = jax.sharding.AbstractMesh
-    try:
-        return mesh_cls(tuple(zip(axis_names, axis_sizes)))
-    except TypeError:
-        return mesh_cls(tuple(axis_sizes), tuple(axis_names))
-
-
 def weight_bytes(cfg, tp: int, wbits: int = 32):
     """(total, per-device) parameter bytes under ``tp``-way serving.
 
@@ -123,7 +111,7 @@ def weight_bytes(cfg, tp: int, wbits: int = 32):
     # [0]: labels are strings, which eval_shape cannot return
     params = jax.eval_shape(
         lambda: T.init_model(jax.random.PRNGKey(0), cfg)[0])
-    mesh = _abstract_mesh((1, tp), ("data", "model"))
+    mesh = jax.sharding.AbstractMesh((1, tp), ("data", "model"))
     with sharding.activate(mesh, sharding.serve_rules(mesh)):
         specs = sharding.param_spec_tree(params)
     total = 0
