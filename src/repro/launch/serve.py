@@ -143,8 +143,11 @@ async def open_loop_run(frontend: AsyncServeFrontend, reqs, offsets):
 
 
 def lifecycle_report(eng: ServeEngine, records=None) -> str:
-    """The lifecycle tail of the serve report line: TTFT/TPOT
-    percentiles, shed/timeout/cancel counts, queue high-water mark."""
+    """The lifecycle tail of the serve report line: TTFT/TPOT and queue
+    wait (submit to admission) percentiles, shed/timeout/cancel counts,
+    queue high-water mark."""
+    waits = [admit - eng.submit_time[uid]
+             for uid, admit in eng.admit_time.items()]
     ttfts, tpots = [], []
     for uid, first in eng.first_token_at.items():
         sub = eng.submit_time.get(uid)
@@ -156,6 +159,7 @@ def lifecycle_report(eng: ServeEngine, records=None) -> str:
             tpots.append((done - first) / (n - 1))
     return (f"TTFT p50/p99 {lat_stats(ttfts)}, "
             f"TPOT p50/p99 {lat_stats(tpots)}, "
+            f"queue wait p50/p99 {lat_stats(waits)}, "
             f"{eng.shed_count} shed, {eng.timeout_count} timed out, "
             f"{eng.cancel_count} cancelled, {eng.fault_count} step faults, "
             f"queue high-water {eng.queue_high_water}")

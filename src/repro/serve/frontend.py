@@ -49,6 +49,7 @@ import dataclasses
 from typing import AsyncIterator, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.serve.scheduler import Request, ServeEngine
 
@@ -67,8 +68,10 @@ class RequestResult:
     ``status`` is the engine's lifecycle terminal (``finished``,
     ``cancelled``, ``timed_out``, ``errored``); ``tokens`` the full or
     partial output; ``reason`` the engine's explanation for any
-    non-finished terminal; timing fields are event-loop wall-clock
-    seconds (``ttft`` None when no token was ever sampled)."""
+    non-finished terminal; timing fields are seconds from the engine's
+    ``submit_time`` stamp, on its clock (``ttft`` to the first token,
+    None when no token was ever sampled; ``latency`` to the terminal
+    state)."""
 
     uid: int
     status: str
@@ -126,7 +129,6 @@ class AsyncServeFrontend:
         self.engine = engine
         self.idle_sleep = idle_sleep
         self._handles: dict[int, RequestHandle] = {}
-        self._submit_times: dict[int, float] = {}
         # the engine is not thread-safe: every engine call runs on this
         # one worker thread, serialized by the loop below
         self._exec = concurrent.futures.ThreadPoolExecutor(
@@ -181,49 +183,46 @@ class AsyncServeFrontend:
         dicts the in-flight device step never reads, and slot-touching
         cancels are deferred by the engine itself while a step is in
         flight)."""
-        loop = asyncio.get_running_loop()
-        while True:
-            try:
-                kind, arg, handle, fut = self._commands.get_nowait()
-            except asyncio.QueueEmpty:
-                return
-            if kind == "submit":
-                reason = self.engine.try_submit(arg)
-                if reason is None:
-                    self._handles[arg.uid] = handle
-                    self._submit_times[arg.uid] = loop.time()
-                fut.set_result(reason)
-            else:                                  # cancel
-                fut.set_result(self.engine.cancel(arg))
+        with TraceAnnotation("frontend.commands"):
+            while True:
+                try:
+                    kind, arg, handle, fut = self._commands.get_nowait()
+                except asyncio.QueueEmpty:
+                    return
+                if kind == "submit":
+                    reason = self.engine.try_submit(arg)
+                    if reason is None:
+                        self._handles[arg.uid] = handle
+                    fut.set_result(reason)
+                else:                              # cancel
+                    fut.set_result(self.engine.cancel(arg))
 
     def _pump_events(self) -> None:
         """Move the engine's stream events into per-request queues and
         resolve terminal futures."""
-        loop = asyncio.get_running_loop()
-        eng = self.engine
-        for ev in eng.drain_events():
-            kind, uid, payload = ev
-            h = self._handles.get(uid)
-            if h is None:
-                continue
-            if kind == "token":
-                h._tokens.put_nowait(int(payload))
-                continue
-            # terminal: build the result record
-            born = self._submit_times.pop(uid, loop.time())
-            first = eng.first_token_at.get(uid)
-            sub = eng.submit_time.get(uid)
-            ttft = (first - sub) if (first is not None
-                                     and sub is not None) else None
-            res = RequestResult(
-                uid=uid, status=payload,
-                tokens=eng.results.get(uid, np.zeros(0, np.int32)),
-                reason=eng.errors.get(uid),
-                ttft=ttft, latency=loop.time() - born)
-            h._tokens.put_nowait(RequestHandle._DONE)
-            if not h._result.done():
-                h._result.set_result(res)
-            del self._handles[uid]
+        with TraceAnnotation("frontend.events"):
+            eng = self.engine
+            for ev in eng.drain_events():
+                kind, uid, payload = ev
+                h = self._handles.get(uid)
+                if h is None:
+                    continue
+                if kind == "token":
+                    h._tokens.put_nowait(int(payload))
+                    continue
+                # terminal: build the result record
+                sub = eng.submit_time[uid]
+                first = eng.first_token_at.get(uid)
+                res = RequestResult(
+                    uid=uid, status=payload,
+                    tokens=eng.results.get(uid, np.zeros(0, np.int32)),
+                    reason=eng.errors.get(uid),
+                    ttft=None if first is None else first - sub,
+                    latency=eng.finished_at[uid] - sub)
+                h._tokens.put_nowait(RequestHandle._DONE)
+                if not h._result.done():
+                    h._result.set_result(res)
+                del self._handles[uid]
 
     async def _loop(self) -> None:
         """Serve until :meth:`stop`: overlap host work with the

@@ -146,6 +146,7 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import devices as devices_lib
 from repro.core.analog import AnalogConfig, AnalogCtx, pack_int4_weights
@@ -1058,12 +1059,15 @@ class ServeEngine:
         self.finished_at: dict[int, float] = {}
         # request lifecycle: per-uid state machine (queued → prefill →
         # decode → {finished, cancelled, timed_out, shed, errored}),
-        # submit timestamps for deadline math, first-token timestamps
-        # for TTFT, and explicit reasons for every non-finished terminal
-        # state — the gating_reasons honesty idiom applied per request
+        # time.perf_counter stamps at submit (deadline math), admission
+        # and first token (submit → admit is the queue wait, admit →
+        # first token the prefill, submit → first token the TTFT), and
+        # explicit reasons for every non-finished terminal state — the
+        # gating_reasons honesty idiom applied per request
         self.status: dict[int, str] = {}
         self.errors: dict[int, str] = {}
         self.submit_time: dict[int, float] = {}
+        self.admit_time: dict[int, float] = {}
         self.first_token_at: dict[int, float] = {}
         # streaming seam: (kind, uid, payload) event log the async
         # frontend drains after each commit — ("token", uid, tok) per
@@ -1096,6 +1100,15 @@ class ServeEngine:
         self.mixed_steps = 0
         self.prefill_chunks = 0
         self.decode_tokens_during_admission = 0
+        # KV occupancy, summed over dispatched steps: cache positions
+        # holding a live request's tokens (left padding excluded) and
+        # the positions the layout holds; their ratio is the
+        # step-weighted share of the reserved cache in use
+        self.kv_used_tokens = 0
+        self.kv_capacity_tokens = 0
+        self._kv_capacity = (self.pool.num_blocks * self.pool.block_size
+                             if self.pool is not None
+                             else b * scfg.max_len)
         # prefix-cache telemetry (hit/skipped tokens count the padded
         # prompt positions the cache covered / the prefill never ran)
         self.prefix_lookups = 0
@@ -1112,12 +1125,11 @@ class ServeEngine:
         self.spec_proposed = 0
         self.spec_accepted = 0
         # drift/recalibration telemetry: deployment hours accumulated,
-        # watchdog health reads, in-place reprogrammings and their cost.
+        # watchdog health reads, in-place reprogrammings.
         # tile_scale_err mirrors the latest watchdog read (mean |scale-1|
         # over live tiles); dead/stuck counts are permanent faults.
         self.drift_hours = 0.0
         self.recal_count = 0
-        self.recal_time = 0.0
         self.watchdog_checks = 0
         self.tile_scale_err = 0.0
         self.dead_tiles = 0
@@ -1271,54 +1283,58 @@ class ServeEngine:
         overtaken by smaller requests behind it, so no request can
         starve.
         """
-        if self._inflight is not None:
-            raise RuntimeError("step_begin with a step already in flight "
-                               "— commit it first (step_commit)")
-        try:
-            self._enforce_deadlines()
-            self._admit_loop()
-            pending = self._dispatch()
-        except Exception as e:                    # noqa: BLE001
-            if not self._tolerant:
-                raise
-            self._fault_reset(e)
-            return None
-        self._inflight = pending
-        return pending
+        with TraceAnnotation("serve.step_begin"):
+            if self._inflight is not None:
+                raise RuntimeError("step_begin with a step already in "
+                                   "flight — commit it first (step_commit)")
+            try:
+                with TraceAnnotation("serve.admit"):
+                    self._enforce_deadlines()
+                    self._admit_loop()
+                pending = self._dispatch()
+            except Exception as e:                # noqa: BLE001
+                if not self._tolerant:
+                    raise
+                self._fault_reset(e)
+                return None
+            self._inflight = pending
+            return pending
 
     def step_commit(self, pending: dict) -> None:
         """Second half: read the dispatched step's results back and run
         the host bookkeeping (token appends, phase flips, registration,
         retirement), then apply any cancellations deferred while the
         step was in flight, then tick the drift clock."""
-        if pending is not self._inflight:
-            raise RuntimeError("step_commit of a step that is not the "
-                               "one in flight")
-        try:
-            {"mixed": self._mixed_commit,
-             "spec": self._spec_commit,
-             "decode": self._decode_commit}[pending["op"]](pending)
-        except Exception as e:                    # noqa: BLE001
+        with TraceAnnotation("serve.step_commit"):
+            if pending is not self._inflight:
+                raise RuntimeError("step_commit of a step that is not the "
+                                   "one in flight")
+            try:
+                {"mixed": self._mixed_commit,
+                 "spec": self._spec_commit,
+                 "decode": self._decode_commit}[pending["op"]](pending)
+            except Exception as e:                # noqa: BLE001
+                self._inflight = None
+                if not self._tolerant:
+                    raise
+                self._fault_reset(e)
+                return
             self._inflight = None
-            if not self._tolerant:
-                raise
-            self._fault_reset(e)
-            return
-        self._inflight = None
-        self.phase_time[pending["kind"]] += (time.perf_counter()
-                                             - pending["t0"])
-        for uid, status, reason in self._deferred_cancels:
-            for b, s in enumerate(self.slots):
-                if s is not None and s.req.uid == uid:
-                    if status == "cancelled":
-                        self.cancel_count += 1
-                    self._retire_slot(b, status, reason)
-                    break          # a finish during commit simply won
-        self._deferred_cancels.clear()
-        # the chip only ages while it computes: idle iterations never
-        # reach a commit, so the deployment clock ticks worked steps only
-        if self._drift:
-            self._advance_drift()
+            self.phase_time[pending["kind"]] += (time.perf_counter()
+                                                 - pending["t0"])
+            for uid, status, reason in self._deferred_cancels:
+                for b, s in enumerate(self.slots):
+                    if s is not None and s.req.uid == uid:
+                        if status == "cancelled":
+                            self.cancel_count += 1
+                        self._retire_slot(b, status, reason)
+                        break          # a finish during commit simply won
+            self._deferred_cancels.clear()
+            # the chip only ages while it computes: idle iterations
+            # never reach a commit, so the deployment clock ticks worked
+            # steps only
+            if self._drift:
+                self._advance_drift()
 
     def _admit_loop(self) -> None:
         """Admit queue heads into free slots (strict FIFO, allocator
@@ -1370,6 +1386,10 @@ class ServeEngine:
             kind = "decode"
         else:
             return None
+        live = [b for b, s in enumerate(self.slots) if s is not None]
+        self.kv_used_tokens += int(np.maximum(
+            self._pos[live] - self._start[live], 0).sum())
+        self.kv_capacity_tokens += self._kv_capacity
         pending["kind"], pending["t0"] = kind, t0
         return pending
 
@@ -1562,7 +1582,6 @@ class ServeEngine:
         self.dead_tiles = h["dead_tiles"]
         self.stuck_cols = h["stuck_cols"]
         if self._recal and self.tile_scale_err > self.scfg.recal_threshold:
-            t0 = time.perf_counter()
             key = jax.random.fold_in(self._recal_key, self.recal_count)
             self.params = devices_lib.recalibrate(self.params, key)
             if self.mesh is not None:
@@ -1574,7 +1593,6 @@ class ServeEngine:
             self.recal_count += 1
             h = devices_lib.health(self.params)
             self.tile_scale_err = h["mean_scale_err"]
-            self.recal_time += time.perf_counter() - t0
 
     def _blocks_needed(self, req: Request) -> int:
         """Worst-case pool blocks a request holds (padded prompt + budget)."""
@@ -1790,6 +1808,7 @@ class ServeEngine:
         slot.hit_snap = snap[0] if snap else 0
         self.slots[b] = slot
         self.status[req.uid] = "prefill"
+        self.admit_time[req.uid] = time.perf_counter()
         self._admit_seq += 1
         self._dirty = True
 
@@ -1855,21 +1874,22 @@ class ServeEngine:
     def _refresh_device_state(self) -> None:
         """Re-upload the per-slot step state from the host mirrors (only
         called when the slot set changed since the last dispatch)."""
-        counts = np.array([s.count if s else 0 for s in self.slots],
-                          np.int32)
-        active = np.array([s is not None and not s.prefilling
-                           for s in self.slots], np.float32)
-        self._dev = {
-            "toks": jnp.asarray(self._last_tok),
-            "off": jnp.asarray(self._pos - self._start),
-            "active": jnp.asarray(active),
-            "keys": jnp.asarray(self._keys),
-            "counts": jnp.asarray(counts),
-            "temp": jnp.asarray(self._temp),
-            "topk": jnp.asarray(self._topk),
-            "topp": jnp.asarray(self._topp),
-            "gfirst": jnp.asarray(self._gfirst),
-        }
+        with TraceAnnotation("serve.upload"):
+            counts = np.array([s.count if s else 0 for s in self.slots],
+                              np.int32)
+            active = np.array([s is not None and not s.prefilling
+                               for s in self.slots], np.float32)
+            self._dev = {
+                "toks": jnp.asarray(self._last_tok),
+                "off": jnp.asarray(self._pos - self._start),
+                "active": jnp.asarray(active),
+                "keys": jnp.asarray(self._keys),
+                "counts": jnp.asarray(counts),
+                "temp": jnp.asarray(self._temp),
+                "topk": jnp.asarray(self._topk),
+                "topp": jnp.asarray(self._topp),
+                "gfirst": jnp.asarray(self._gfirst),
+            }
         self._dirty = False
 
     def _decode_args(self):
@@ -1926,13 +1946,16 @@ class ServeEngine:
                 mesh=self.mesh)
 
         use_top_k, use_top_p = self._sample_flags()
-        dec_toks, first, toks, off, counts, self.caches = _mixed_step_jit(
-            self.params, self.caches, *self._decode_args(),
-            pf_idx=jnp.asarray(pf_idx), pf_toks=jnp.asarray(pf_toks),
-            pf_mask=jnp.asarray(pf_mask), pf_off=jnp.asarray(pf_off),
-            cfg=self.cfg, acfg=self.acfg, use_top_k=use_top_k,
-            use_top_p=use_top_p, k=k, paged=self._paged,
-            snaps=self._snaps, mesh=self.mesh)
+        with TraceAnnotation("serve.dispatch"):
+            dec_toks, first, toks, off, counts, self.caches = (
+                _mixed_step_jit(
+                    self.params, self.caches, *self._decode_args(),
+                    pf_idx=jnp.asarray(pf_idx), pf_toks=jnp.asarray(pf_toks),
+                    pf_mask=jnp.asarray(pf_mask),
+                    pf_off=jnp.asarray(pf_off), cfg=self.cfg,
+                    acfg=self.acfg, use_top_k=use_top_k,
+                    use_top_p=use_top_p, k=k, paged=self._paged,
+                    snaps=self._snaps, mesh=self.mesh))
         self._stash(toks, off, counts)
         if k:
             self.mixed_steps += 1          # steps that fused both phases
@@ -1958,7 +1981,8 @@ class ServeEngine:
                 self._maybe_snapshot(b, s)
             if not s.prefilling:               # prompt done: first token
                 if first_host is None:
-                    first_host = np.asarray(first)
+                    with TraceAnnotation("serve.readback"):
+                        first_host = np.asarray(first)
                 if self._prefix:
                     # index the prompt's blocks/snapshots before the
                     # first token can retire the request (release must
@@ -1986,8 +2010,9 @@ class ServeEngine:
         if k:
             self.decode_steps += k
             self.decode_tokens_during_admission += p["n_dec"] * k
-            self._consume_decode_tokens(np.asarray(p["dec_toks"]),
-                                        p["decode_rows"])
+            with TraceAnnotation("serve.readback"):
+                dec_toks = np.asarray(p["dec_toks"])
+            self._consume_decode_tokens(dec_toks, p["decode_rows"])
 
     def _spec_k(self, decode_rows: list[int]) -> int:
         """Window size of the next speculative step: ``draft_k`` clipped
@@ -2045,22 +2070,24 @@ class ServeEngine:
         use_top_k, use_top_p = self._sample_flags()
         if self._draft_host:
             drafts = self._host_drafts(decode_rows, k)
-            target, n_emit, toks, off, counts, self.caches = (
-                _spec_verify_jit(
-                    self.params, self.caches, *self._decode_args(),
-                    jnp.asarray(drafts), cfg=self.cfg, acfg=self.acfg,
-                    use_top_k=use_top_k, use_top_p=use_top_p,
-                    paged=self._paged, snaps=self._snaps,
-                    mesh=self.mesh))
+            with TraceAnnotation("serve.dispatch"):
+                target, n_emit, toks, off, counts, self.caches = (
+                    _spec_verify_jit(
+                        self.params, self.caches, *self._decode_args(),
+                        jnp.asarray(drafts), cfg=self.cfg, acfg=self.acfg,
+                        use_top_k=use_top_k, use_top_p=use_top_p,
+                        paged=self._paged, snaps=self._snaps,
+                        mesh=self.mesh))
         else:
-            (target, n_emit, toks, off, counts, self.caches,
-             self.draft_caches) = _spec_step_jit(
-                self.params, self.draft_params, self.caches,
-                self.draft_caches, *self._decode_args(),
-                cfg=self.cfg, acfg=self.acfg, dcfg=self.draft_cfg,
-                dacfg=self.draft_acfg, use_top_k=use_top_k,
-                use_top_p=use_top_p, k=k, paged=self._paged,
-                snaps=self._snaps, mesh=self.mesh)
+            with TraceAnnotation("serve.dispatch"):
+                (target, n_emit, toks, off, counts, self.caches,
+                 self.draft_caches) = _spec_step_jit(
+                    self.params, self.draft_params, self.caches,
+                    self.draft_caches, *self._decode_args(),
+                    cfg=self.cfg, acfg=self.acfg, dcfg=self.draft_cfg,
+                    dacfg=self.draft_acfg, use_top_k=use_top_k,
+                    use_top_p=use_top_p, k=k, paged=self._paged,
+                    snaps=self._snaps, mesh=self.mesh)
         self._stash(toks, off, counts)
         if self.pool is not None:
             self.pool.begin_window(self.slots[b].req.uid
@@ -2074,7 +2101,9 @@ class ServeEngine:
         each row's emitted tokens and check the rewind-safety
         contract."""
         decode_rows, k = p["decode_rows"], p["k"]
-        target, n_emit = np.asarray(p["target"]), np.asarray(p["n_emit"])
+        with TraceAnnotation("serve.readback"):
+            target = np.asarray(p["target"])
+            n_emit = np.asarray(p["n_emit"])
         if self.pool is not None:
             self.pool.end_window()
         if k:                     # a k=0 window is just a decode step
@@ -2110,11 +2139,12 @@ class ServeEngine:
         while k * 2 <= min(remaining, self.scfg.decode_block):
             k *= 2
         use_top_k, use_top_p = self._sample_flags()
-        dec_toks, toks, off, counts, self.caches = _step_jit(
-            self.params, self.caches, *self._decode_args(),
-            cfg=self.cfg, acfg=self.acfg,
-            use_top_k=use_top_k, use_top_p=use_top_p, k=k,
-            mesh=self.mesh)
+        with TraceAnnotation("serve.dispatch"):
+            dec_toks, toks, off, counts, self.caches = _step_jit(
+                self.params, self.caches, *self._decode_args(),
+                cfg=self.cfg, acfg=self.acfg,
+                use_top_k=use_top_k, use_top_p=use_top_p, k=k,
+                mesh=self.mesh)
         self._stash(toks, off, counts)
         self.decode_steps += k
         self.step_token_log.append((len(decode_rows) * k, 0))
@@ -2124,8 +2154,9 @@ class ServeEngine:
     def _decode_commit(self, p: dict) -> None:
         """Commit half of the decode block: read the sampled tokens back
         and append them to their requests."""
-        self._consume_decode_tokens(np.asarray(p["dec_toks"]),
-                                    p["decode_rows"])
+        with TraceAnnotation("serve.readback"):
+            dec_toks = np.asarray(p["dec_toks"])
+        self._consume_decode_tokens(dec_toks, p["decode_rows"])
 
     def _consume_decode_tokens(self, toks: np.ndarray,
                                decode_rows: list[int]) -> None:

@@ -23,7 +23,9 @@ PR 9's contracts over the continuous-batching engine:
   arbitrary lifecycle interleavings). Property-based when ``hypothesis``
   is installed (``strategies`` guard), seeded-random always;
 * the **async frontend** (``serve.frontend``): token streaming matches
-  terminal results, cancel mid-stream, deterministic ``ShedError``.
+  terminal results, cancel mid-stream, deterministic ``ShedError``;
+* **lifecycle telemetry** — submit, admission and first-token stamps in
+  order on one clock, and the KV occupancy counters' hand count.
 """
 
 import asyncio
@@ -514,6 +516,58 @@ def test_frontend_closed_loop_parity():
     for res in asyncio.run(scenario()):
         assert res.status == "finished"
         np.testing.assert_array_equal(res.tokens, ref[res.uid])
+
+
+def test_lifecycle_stamps_are_ordered():
+    """Every finished request is stamped at submit, at admission and at
+    its first token, in that order; the frontend's TTFT and latency both
+    run from the engine's submit stamp. Four requests on two slots, so
+    two of them wait in the queue."""
+    cfg, params, _ = _build("granite-3-8b")
+    eng = ServeEngine(params, cfg, AnalogConfig(mode="off"), _scfg())
+    fe = AsyncServeFrontend(eng)
+
+    async def scenario():
+        await fe.start()
+        handles = [await fe.submit(r) for r in _reqs(cfg, 4, seed=2)]
+        out = [await h.result() for h in handles]
+        await fe.stop()
+        return out
+
+    for res in asyncio.run(scenario()):
+        uid = res.uid
+        assert res.status == "finished"
+        sub, admit = eng.submit_time[uid], eng.admit_time[uid]
+        first, done = eng.first_token_at[uid], eng.finished_at[uid]
+        assert sub <= admit <= first <= done
+        assert res.ttft == first - sub and res.latency == done - sub
+    waits = sorted(eng.admit_time[u] - eng.submit_time[u] for u in range(4))
+    assert waits[-1] > waits[0]         # the queued pair waited
+
+
+@pytest.mark.parametrize("paged,capacity", [(False, 2 * 32),
+                                            (True, 10 * 4)])
+def test_kv_counters_hand_count(paged, capacity):
+    """The KV occupancy counters on a scripted two-request run. Chunk 4,
+    budget 10 (two chunks a step). A: 6 prompt tokens (2 left pads),
+    3 new; B: 3 prompt tokens (1 pad), 2 new. At each dispatch the
+    counter adds, per live slot, ``pos - start`` floored at 0:
+    step 1, both prefill their first chunk: A 0-2 -> 0, B 0-1 -> 0;
+    step 2, A's second chunk fused with B's decode: A 4-2, B 4-1 -> 5
+    (B retires with its second token); step 3, A decodes a block of
+    2: A 8-2 -> 6. Capacity: 3 steps of the layout's positions (2 slots
+    of 32, or 10 blocks of 4)."""
+    cfg, params, _ = _build("granite-3-8b")
+    eng = ServeEngine(params, cfg, AnalogConfig(mode="off"),
+                      _scfg(paged=paged, kv_blocks=10 if paged else 0))
+    eng.run([Request(uid=0, prompt=_prompt(cfg, 6), max_new=3,
+                     temperature=0.0),
+             Request(uid=1, prompt=_prompt(cfg, 3, seed=4), max_new=2,
+                     temperature=0.0)])
+    assert [len(eng.results[u]) for u in (0, 1)] == [3, 2]
+    assert len(eng.step_token_log) == 3
+    assert eng.kv_used_tokens == 0 + 5 + 6
+    assert eng.kv_capacity_tokens == 3 * capacity
 
 
 @pytest.mark.parametrize("fault", [False, True])
