@@ -201,8 +201,18 @@ def _chunked_causal_attention(q, k, v, scale, q_chunk=512, kv_chunk=1024):
 
 def attention(p: dict, x: jax.Array, cfg, acfg: AnalogConfig, ctx: AnalogCtx,
               positions: jax.Array, cache: dict | None = None,
-              seq_mask: jax.Array | None = None):
+              seq_mask: jax.Array | None = None, layer: tuple = ()):
     """GQA attention block. Returns (y, stats, new_cache).
+
+    ``cache`` is the whole layer-stacked decoding cache and ``layer`` the
+    leading indices of this layer in it (``(i,)`` for a uniform stack,
+    empty for a single layer's cache; see ``layer_slice``): the layer
+    writes only its new token rows into the stacked buffers, at
+    ``[*layer, b, pos[b] + i]``, reads its own entry back for the scores,
+    and returns the whole stacked cache. The per-row leaves (``pos``) are
+    written back at ``layer``; every other leaf passes through untouched.
+    That is what lets ``transformer.apply_blocks`` carry the cache through
+    its layer scan without a copy per layer.
 
     ``seq_mask`` [B, S] (1 = real token) applies to the slot-cache layouts
     only and makes *fully-masked rows* cache-transparent: their K/V writes
@@ -215,7 +225,7 @@ def attention(p: dict, x: jax.Array, cfg, acfg: AnalogConfig, ctx: AnalogCtx,
     ``start`` marker keeps them unattended), so all-active callers are
     bit-identical to the unmasked path.
 
-    Two cache layouts (see ``init_cache``):
+    Three cache layouts (see ``init_cache``; shapes are one layer's):
 
     * legacy (``pos`` scalar): ``{"k": [B, T, KV, hd], "v": ..., "pos": ()}``
       — batched lockstep serving; decode writes one token at the shared
@@ -223,7 +233,8 @@ def attention(p: dict, x: jax.Array, cfg, acfg: AnalogConfig, ctx: AnalogCtx,
     * slot mode (``pos`` [B]): ``{"k", "v", "pos": [B], "start": [B]}`` —
       the continuous-batching layout. Every row is an independent request
       slot: the current chunk (decode: S=1, chunked prefill: S=C) is
-      scattered at per-row write indices ``pos[b] + arange(S)`` and the
+      scattered into the stacked buffers at ``[*layer, b, pos[b] +
+      arange(S)]``, the layer's entry is read back, and the
       mask attends cache indices ``start[b] <= j <= pos[b] + i`` only, so
       left-pad rows (``j < start``) and unwritten rows are never attended.
       All index math is static-shape (gather/scatter), keeping the decode
@@ -267,49 +278,43 @@ def attention(p: dict, x: jax.Array, cfg, acfg: AnalogConfig, ctx: AnalogCtx,
     scale = cfg.head_dim ** -0.5
 
     if cache is not None and "kp" in cache:          # paged slot mode
-        out, new_cache = _paged_slot_attention(cache, q, k, v, x, scale,
-                                               acfg.kv_splits, seq_mask)
-    elif cache is not None and jnp.ndim(cache["pos"]) == 1:   # slot mode
-        pos, start = cache["pos"], cache["start"]
+        out, new_cache = _paged_slot_attention(cache, layer, q, k, v, x,
+                                               scale, acfg.kv_splits,
+                                               seq_mask)
+    elif cache is not None:
+        pos = layer_slice(cache["pos"], layer)
         bsz, s = x.shape[0], x.shape[1]
-        t = cache["k"].shape[1]
-        row_on = _row_active(seq_mask, bsz)                  # [B] 0/1
-        idx = pos[:, None] + jnp.arange(s)[None, :]          # [B, S] writes
-        idx_w = jnp.where(row_on[:, None] > 0, idx, t)       # drop if inactive
-        b_idx = jnp.arange(bsz)[:, None]
-        k_buf = cache["k"].at[b_idx, idx_w].set(
-            k.astype(cache["k"].dtype), mode="drop")
-        v_buf = cache["v"].at[b_idx, idx_w].set(
-            v.astype(cache["v"].dtype), mode="drop")
-        j = jnp.arange(t)[None, None, :]
-        mask = (j >= start[:, None, None]) & (j <= idx[:, :, None])
-        out = _gqa_scores_softmax_v(q, k_buf, v_buf, mask, scale)
-        new_cache = {"k": k_buf, "v": v_buf, "pos": pos + s * row_on,
-                     "start": start}
-    elif cache is not None and x.shape[1] == 1:     # legacy decode step
-        pos = cache["pos"]
-        k_buf = jax.lax.dynamic_update_slice(
-            cache["k"], k.astype(cache["k"].dtype), (0, pos, 0, 0))
-        v_buf = jax.lax.dynamic_update_slice(
-            cache["v"], v.astype(cache["v"].dtype), (0, pos, 0, 0))
-        t = k_buf.shape[1]
-        mask = jnp.broadcast_to((jnp.arange(t) <= pos)[None, None, :],
-                                (x.shape[0], 1, t))
-        out = _gqa_scores_softmax_v(q, k_buf, v_buf, mask, scale)
-        new_cache = {"k": k_buf, "v": v_buf, "pos": pos + 1}
-    else:                                            # train / prefill
-        if x.shape[1] <= 1024:
-            t = k.shape[1]
-            mask = (positions[:, :, None] >= jnp.arange(t)[None, None, :])
-            out = _gqa_scores_softmax_v(q, k, v, mask, scale)
+        t = cache["k"].shape[len(layer) + 1]
+        if pos.ndim == 1:                            # slot mode
+            start = layer_slice(cache["start"], layer)
+            row_on = _row_active(seq_mask, bsz)              # [B] 0/1
+            idx = pos[:, None] + jnp.arange(s)[None, :]      # [B, S] writes
+            idx_w = jnp.where(row_on[:, None] > 0, idx, t)   # drop if inactive
+            at = layer + (jnp.arange(bsz)[:, None], idx_w)
+            write = lambda buf, val: buf.at[at].set(val.astype(buf.dtype),
+                                                    mode="drop")
+            j = jnp.arange(t)[None, None, :]
+            mask = (j >= start[:, None, None]) & (j <= idx[:, :, None])
+            new_pos = pos + s * row_on
+        else:               # legacy: decode writes at pos, prefill at 0
+            at = layer + (0, pos if s == 1 else 0, 0, 0)
+            write = lambda buf, val: jax.lax.dynamic_update_slice(
+                buf, _lead(val.astype(buf.dtype), layer), at)
+            mask = (jnp.broadcast_to((jnp.arange(t) <= pos)[None, None, :],
+                                     (bsz, 1, t)) if s == 1 else None)
+            new_pos = pos + s
+        k_buf, v_buf = write(cache["k"], k), write(cache["v"], v)
+        if mask is None:    # prefill attends over its own chunk
+            out = _causal_attention(q, k, v, positions, scale)
         else:
-            out = _chunked_causal_attention(q, k, v, scale)
-        if cache is not None:                        # prefill fills the cache
-            new_cache = {
-                "k": _fill_cache(cache["k"], k), "v": _fill_cache(cache["v"], v),
-                "pos": cache["pos"] + x.shape[1]}
-        else:
-            new_cache = None
+            out = _gqa_scores_softmax_v(q, layer_slice(k_buf, layer),
+                                        layer_slice(v_buf, layer), mask,
+                                        scale)
+        new_cache = {**cache, "k": k_buf, "v": v_buf,
+                     "pos": layer_update(cache["pos"], layer, new_pos)}
+    else:                                            # train
+        out = _causal_attention(q, k, v, positions, scale)
+        new_cache = None
 
     out = out.reshape(*x.shape[:-1], cfg.num_heads * cfg.head_dim)
     # "attn_out" == "heads" under training rules; serve rules replicate it
@@ -319,10 +324,38 @@ def attention(p: dict, x: jax.Array, cfg, acfg: AnalogConfig, ctx: AnalogCtx,
     return y, {**stats_in, "o": st_o}, new_cache
 
 
-def _fill_cache(buf, new):
-    """Write prefill k/v into the front of a statically-shaped cache."""
+def _causal_attention(q, k, v, positions, scale):
+    """Causal self-attention of a whole sequence (train / lockstep
+    prefill): the dense mask up to 1024 tokens, flash-style beyond."""
+    if q.shape[1] <= 1024:
+        t = k.shape[1]
+        mask = positions[:, :, None] >= jnp.arange(t)[None, None, :]
+        return _gqa_scores_softmax_v(q, k, v, mask, scale)
+    return _chunked_causal_attention(q, k, v, scale)
+
+
+def layer_slice(leaf: jax.Array, layer: tuple) -> jax.Array:
+    """One layer's entry of a layer-stacked cache leaf: ``layer`` holds the
+    (traced or static) indices of the leaf's leading stack axes — ``(i,)``
+    for a uniform stack, ``(sb, m)`` for a hybrid super-block's mamba
+    leaves, empty for an unstacked leaf."""
+    n = len(layer)
+    sl = jax.lax.dynamic_slice(leaf, tuple(layer) + (0,) * (leaf.ndim - n),
+                               (1,) * n + leaf.shape[n:])
+    return sl.reshape(leaf.shape[n:])
+
+
+def layer_update(leaf: jax.Array, layer: tuple, val: jax.Array) -> jax.Array:
+    """Write one layer's entry ``val`` back into a layer-stacked cache leaf
+    (the inverse of :func:`layer_slice`)."""
     return jax.lax.dynamic_update_slice(
-        buf, new.astype(buf.dtype), (0, 0, 0, 0))
+        leaf, _lead(val.astype(leaf.dtype), layer),
+        tuple(layer) + (0,) * val.ndim)
+
+
+def _lead(val: jax.Array, layer: tuple) -> jax.Array:
+    """``val`` with one unit axis per stack index in ``layer``."""
+    return val.reshape((1,) * len(layer) + val.shape)
 
 
 def _row_active(seq_mask, bsz):
@@ -335,10 +368,13 @@ def _row_active(seq_mask, bsz):
     return (jnp.max(seq_mask, axis=1) > 0).astype(jnp.int32)
 
 
-def _paged_slot_attention(cache, q, k, v, x, scale, kv_splits=1,
+def _paged_slot_attention(cache, layer, q, k, v, x, scale, kv_splits=1,
                           seq_mask=None):
     """Paged-pool branch of :func:`attention`: scatter-write the current
-    chunk into the block pool, then score against the live range only.
+    chunk into the layer's entry of the stacked block pool, at
+    ``[*layer, blk, off]``, then score against the live range only. The
+    attention ops get the whole stacked pool, viewed as one pool of blocks,
+    and the read table offset to the layer's entry (``_flat_pool``).
 
     Decode (S=1) routes through the paged flash-decode op; chunked prefill
     routes through the paged flash-prefill op — the chunk's queries score
@@ -351,45 +387,56 @@ def _paged_slot_attention(cache, q, k, v, x, scale, kv_splits=1,
     rewrites instead of touching blocks other slots read. Fully-masked
     rows (``seq_mask`` all zero) write to the sink and keep their
     cursor."""
-    pos, start, tbl = cache["pos"], cache["start"], cache["tbl"]
-    wtbl = cache.get("wtbl", tbl)
+    at_layer = lambda name: layer_slice(cache[name], layer)
+    pos, start, tbl = at_layer("pos"), at_layer("start"), at_layer("tbl")
+    wtbl = at_layer("wtbl") if "wtbl" in cache else tbl
     bsz, s = x.shape[0], x.shape[1]
-    bs = cache["kp"].shape[1]
-    quantized = "ks" in cache
+    bs = cache["kp"].shape[len(layer) + 1]
     row_on = _row_active(seq_mask, bsz)                      # [B] 0/1
     idx = pos[:, None] + jnp.arange(s)[None, :]              # [B, S] logical
     blk = jnp.take_along_axis(wtbl, idx // bs, axis=1)       # [B, S] physical
     blk = jnp.where(row_on[:, None] > 0, blk, 0)             # sink if inactive
-    off = idx % bs
-    new_cache = dict(cache)
-    if quantized:
+    at = layer + (blk, idx % bs)
+    if "ks" in cache:                                        # int8 pool
         kq, ks = quant.kv_quantize(k, 8)
         vq, vs = quant.kv_quantize(v, 8)
-        new_cache["kp"] = cache["kp"].at[blk, off].set(kq, mode="drop")
-        new_cache["vp"] = cache["vp"].at[blk, off].set(vq, mode="drop")
-        new_cache["ks"] = cache["ks"].at[blk, off].set(
-            ks.astype(cache["ks"].dtype), mode="drop")
-        new_cache["vs"] = cache["vs"].at[blk, off].set(
-            vs.astype(cache["vs"].dtype), mode="drop")
+        rows = {"kp": kq, "vp": vq, "ks": ks, "vs": vs}
     else:
-        new_cache["kp"] = cache["kp"].at[blk, off].set(
-            k.astype(cache["kp"].dtype), mode="drop")
-        new_cache["vp"] = cache["vp"].at[blk, off].set(
-            v.astype(cache["vp"].dtype), mode="drop")
-    new_cache["pos"] = pos + s * row_on
+        rows = {"kp": k, "vp": v}
+    new_cache = dict(cache)
+    for name, val in rows.items():
+        new_cache[name] = cache[name].at[at].set(
+            val.astype(cache[name].dtype), mode="drop")
+    new_cache["pos"] = layer_update(cache["pos"], layer, pos + s * row_on)
+    # the ops read the stacked pool in place, through this layer's tables
+    pool = {name: _flat_pool(new_cache[name], layer)[0] for name in rows}
+    tbl = tbl + _flat_pool(cache["kp"], layer)[1]
 
     if s == 1:                                    # decode: flash over blocks
         out = dispatch.paged_decode_attention(
-            q[:, 0], new_cache["kp"], new_cache["vp"], tbl, pos, start,
-            scale, k_scale=new_cache.get("ks"),
-            v_scale=new_cache.get("vs"), num_splits=kv_splits)
+            q[:, 0], pool["kp"], pool["vp"], tbl, pos, start, scale,
+            k_scale=pool.get("ks"), v_scale=pool.get("vs"),
+            num_splits=kv_splits)
         return out[:, None].astype(q.dtype), new_cache
 
     # chunked prefill: flash over blocks, in place on the pool
     out = dispatch.paged_prefill_attention(
-        q, new_cache["kp"], new_cache["vp"], tbl, pos, start, scale,
-        k_scale=new_cache.get("ks"), v_scale=new_cache.get("vs"))
+        q, pool["kp"], pool["vp"], tbl, pos, start, scale,
+        k_scale=pool.get("ks"), v_scale=pool.get("vs"))
     return out.astype(q.dtype), new_cache
+
+
+def _flat_pool(leaf: jax.Array, layer: tuple):
+    """A stacked pool leaf ``[*stack, P, ...]`` as one pool of blocks
+    ``[N * P, ...]`` (a free reshape), and the index of the first block of
+    the entry at ``layer`` in it: a block table offset by that index
+    addresses the layer's blocks, so no layer's pool is sliced out (a
+    kernel's operand would have to be a copy)."""
+    n = len(layer)
+    first = 0
+    for i, d in zip(layer, leaf.shape[:n]):
+        first = first * d + i
+    return leaf.reshape((-1,) + leaf.shape[n + 1:]), first * leaf.shape[n]
 
 
 def init_cache(cfg, batch: int, max_len: int, dtype=jnp.float32,

@@ -16,6 +16,7 @@ from repro.core.analog import (AnalogConfig, AnalogCtx, analog_linear,
                                init_linear, linear_labels)
 from repro.distributed.sharding import shard_hint
 from repro.kernels import ops as kops
+from repro.models import layers as L
 
 
 def _dims(cfg):
@@ -83,11 +84,16 @@ def _gated_rmsnorm(y, z, scale, eps=1e-5):
 
 
 def mamba(p: dict, x: jax.Array, cfg, acfg: AnalogConfig, ctx: AnalogCtx,
-          cache: dict | None = None, seq_mask: jax.Array | None = None):
+          cache: dict | None = None, seq_mask: jax.Array | None = None,
+          layer: tuple = ()):
     """SSD mixer over x [B, S, d]. Returns (y, stats, new_cache).
 
-    cache: {"conv": [B, W-1, conv_ch], "ssm": [B, H, N, P]} for decode;
-    prefill (cache passed, S > 1) fills it; train (cache None) skips state.
+    cache: the layer-stacked decoding cache, whose entry at the leading
+    indices ``layer`` (see ``layers.layer_slice``) is this mixer's
+    {"conv": [B, W-1, conv_ch], "ssm": [B, H, N, P]}; decode (S = 1)
+    advances it, prefill (S > 1) continues it, and the new state is
+    written back at ``layer`` into the returned stacked cache (other
+    leaves pass through). Train (cache None) skips state.
 
     ``seq_mask`` [B, S] (1 = real token) makes padded/inactive positions
     state-transparent, which is what the continuous-batching scheduler's
@@ -114,7 +120,11 @@ def mamba(p: dict, x: jax.Array, cfg, acfg: AnalogConfig, ctx: AnalogCtx,
     if seq_mask is not None:
         xbc = xbc * seq_mask[..., None].astype(xbc.dtype)
 
-    conv_state = cache["conv"] if cache is not None else None
+    if cache is not None:
+        conv_state = L.layer_slice(cache["conv"], layer)
+        ssm_state = L.layer_slice(cache["ssm"], layer)
+    else:
+        conv_state = ssm_state = None
     xbc, new_conv = _causal_conv(xbc, p["conv_w"], p["conv_b"], conv_state)
     xs, b, c = jnp.split(xbc, [d_inner, d_inner + gn], axis=-1)
 
@@ -138,32 +148,31 @@ def mamba(p: dict, x: jax.Array, cfg, acfg: AnalogConfig, ctx: AnalogCtx,
     # (the SSM state is already transparent through dt = 0; the conv
     # state needs this explicit freeze).
     conv_cast = (None if cache is None
-                 else cache["conv"] if new_conv is None
-                 else new_conv.astype(cache["conv"].dtype))
+                 else conv_state if new_conv is None
+                 else new_conv.astype(conv_state.dtype))
     if cache is not None and seq_mask is not None and new_conv is not None:
         row_on = jnp.max(seq_mask, axis=1) > 0                # [B]
-        conv_cast = jnp.where(row_on[:, None, None], conv_cast,
-                              cache["conv"])
+        conv_cast = jnp.where(row_on[:, None, None], conv_cast, conv_state)
     if cache is not None and s == 1:                              # decode
         rep = heads // g
         to_bh = lambda t: t[:, 0].repeat(rep, axis=1).reshape(bsz * heads, -1)
         h, y_t = kops.ssd_decode_step(
-            cache["ssm"].reshape(bsz * heads, n, pdim),
+            ssm_state.reshape(bsz * heads, n, pdim),
             xh[:, 0].reshape(bsz * heads, pdim),
             dt[:, 0].reshape(bsz * heads), jnp.tile(a, bsz),
             to_bh(bg), to_bh(cg))
         y = y_t.reshape(bsz, 1, heads, pdim)
-        # {**cache, ...} passes extra leaves (the scheduler's *_snap
-        # snapshot pools) through untouched
-        new_cache = {**cache, "conv": conv_cast,
-                     "ssm": h.reshape(bsz, heads, n, pdim)}
     else:
-        h0 = (cache["ssm"].reshape(bsz * heads, n, pdim)
+        h0 = (ssm_state.reshape(bsz * heads, n, pdim)
               if cache is not None else None)
-        y, h_final = _ssd_with_state(xh, dt, a, bg, cg, h0)
-        new_cache = ({**cache, "conv": conv_cast,
-                      "ssm": h_final.reshape(bsz, heads, n, pdim)}
-                     if cache is not None else None)
+        y, h = _ssd_with_state(xh, dt, a, bg, cg, h0)
+    # {**cache, ...} passes extra leaves (the scheduler's *_snap snapshot
+    # pools) through untouched
+    new_cache = (None if cache is None else
+                 {**cache,
+                  "conv": L.layer_update(cache["conv"], layer, conv_cast),
+                  "ssm": L.layer_update(cache["ssm"], layer,
+                                        h.reshape(bsz, heads, n, pdim))})
 
     y = y + p["d_skip"][None, None, :, None] * xh.astype(jnp.float32)
     y = y.reshape(bsz, s, d_inner).astype(x.dtype)

@@ -76,11 +76,14 @@ def attn_layer_labels(p, ffn_kind: str):
 
 
 def apply_attn_layer(p, x, cfg, acfg, ctx, positions, cache, ffn_kind: str,
-                     seq_mask=None):
-    """One attention block with residuals. Returns (x, stats, cache)."""
+                     seq_mask=None, layer: tuple = ()):
+    """One attention block with residuals. Returns (x, stats, cache).
+
+    ``cache`` is the layer-stacked cache and ``layer`` this block's leading
+    indices in it (see ``layers.attention``)."""
     h, st_a, new_cache = L.attention(
         p["attn"], L.apply_norm(p["ln1"], x, cfg.norm), cfg, acfg, ctx,
-        positions, cache, seq_mask)
+        positions, cache, seq_mask, layer)
     x = x + h
     h, st_f = _apply_ffn(p["ffn"], L.apply_norm(p["ln2"], x, cfg.norm),
                          cfg, acfg, ctx, ffn_kind)
@@ -110,11 +113,14 @@ def mamba_layer_labels(p, ffn_kind: str):
 
 
 def apply_mamba_layer(p, x, cfg, acfg, ctx, cache, ffn_kind: str,
-                      seq_mask=None):
-    """One mamba block with residuals. Returns (x, stats, cache)."""
+                      seq_mask=None, layer: tuple = ()):
+    """One mamba block with residuals. Returns (x, stats, cache).
+
+    ``cache`` is the layer-stacked cache and ``layer`` this block's leading
+    indices in it (see ``mamba2.mamba``)."""
     h, st_m, new_cache = M.mamba(
         p["mixer"], L.apply_norm(p["ln1"], x, cfg.norm), cfg, acfg, ctx, cache,
-        seq_mask=seq_mask)
+        seq_mask=seq_mask, layer=layer)
     x = x + h
     stats = {"mixer": st_m}
     if ffn_kind != "none":
@@ -205,16 +211,21 @@ def blocks_labels(params_blocks, cfg):
     return lab
 
 
-def _hybrid_sb_apply(p_sb, x, cfg, acfg, ctx, positions, cache_sb,
+def _hybrid_sb_apply(p_sb, x, cfg, acfg, ctx, positions, cache, layer,
                      seq_mask=None):
     """One Jamba super-block: layers 0..attn_every-1, attn at the middle.
+
+    ``cache`` is the stacked hybrid cache and ``layer`` this super-block's
+    leading indices in it (``(sb,)`` in the scan): the attention layer
+    works at ``layer`` of the ``attn`` leaves, mamba layer ``m`` at
+    ``(*layer, m)`` of the ``mamba`` leaves; the updated cache is
+    returned.
 
     Returned stats mirror the super-block's param structure (attn / mamba /
     dense_ffn / moe_ffn with stacked sub-stats) so the trainer's input-range
     rules can walk params and stats in lockstep.
     """
     half = cfg.attn_every // 2
-    new_cache = {"attn": None, "mamba": []}
     st_attn, st_mamba, st_dense, st_moe = None, [], [], []
     m_idx = 0
     take = lambda t, i: jax.tree.map(lambda a: a[i], t)
@@ -223,17 +234,19 @@ def _hybrid_sb_apply(p_sb, x, cfg, acfg, ctx, positions, cache_sb,
         ctx_j = dataclasses.replace(
             ctx, key=None if ctx.key is None else jax.random.fold_in(ctx.key, j))
         if j == half:
-            c = None if cache_sb is None else cache_sb["attn"]
+            c = None if cache is None else cache["attn"]
             x, st_attn, nc = apply_attn_layer(p_sb["attn"], x, cfg, acfg,
                                               ctx_j, positions, c, "dense",
-                                              seq_mask)
-            new_cache["attn"] = nc
+                                              seq_mask, layer)
+            if cache is not None:
+                cache = {**cache, "attn": nc}
         else:
             mp = take(p_sb["mamba"], m_idx)
-            c = None if cache_sb is None else take(cache_sb["mamba"], m_idx)
+            c = None if cache is None else cache["mamba"]
             x, st_m, nc = apply_mamba_layer(mp, x, cfg, acfg, ctx_j, c, "none",
-                                            seq_mask)
-            new_cache["mamba"].append(nc)
+                                            seq_mask, layer + (m_idx,))
+            if cache is not None:
+                cache = {**cache, "mamba": nc}
             st_mamba.append(st_m)
             m_idx += 1
             if ffn_kind == "moe":
@@ -246,20 +259,25 @@ def _hybrid_sb_apply(p_sb, x, cfg, acfg, ctx, positions, cache_sb,
             x = x + h
             (st_moe if ffn_kind == "moe" else st_dense).append({"ffn": st_f})
 
-    if cache_sb is not None:
-        new_cache["mamba"] = jax.tree.map(
-            lambda *xs: jnp.stack(xs), *new_cache["mamba"])
-    else:
-        new_cache = None
     stack = lambda lst: jax.tree.map(lambda *xs: jnp.stack(xs), *lst)
     stats = {"attn": st_attn, "mamba": stack(st_mamba),
              "dense_ffn": stack(st_dense), "moe_ffn": stack(st_moe)}
-    return x, stats, new_cache
+    return x, stats, cache
 
 
 def apply_blocks(params_blocks, x, cfg, acfg: AnalogConfig, ctx: AnalogCtx,
                  positions, caches=None, remat: bool = False, seq_mask=None):
     """Scan the layer stack. Returns (x, stats_stacked, new_caches).
+
+    The parameters are the scan's ``xs``; the layer-stacked decoding
+    cache rides in the carry with the layer index, whole. Each layer
+    writes only its new token rows (and its O(1)-per-slot state) into the
+    stacked, donated buffers at its own index and reads its entry back
+    (``layers.attention``, ``mamba2.mamba``), so no layer copies its part
+    of the cache. (The executable around the scan may still copy the
+    whole cache into the loop's layout and back, where the device's
+    default layout of a leaf is another: ``docs/serving.md``.) Training
+    passes ``caches=None``.
 
     ``seq_mask`` [B, S] marks valid (non-pad) positions; it is forwarded to
     the stateful mamba mixers so masked tokens leave the SSM/conv state
@@ -271,38 +289,27 @@ def apply_blocks(params_blocks, x, cfg, acfg: AnalogConfig, ctx: AnalogCtx,
     chunks of admitting slots in one dispatch.
     """
     fam = cfg.family
-    with_cache = caches is not None
+    ffn_kind = {"dense": "dense", "vlm": "dense", "audio": "dense",
+                "moe": "moe", "ssm": "none", "hybrid": None}[fam]
 
-    if fam == "hybrid":
-        def body(carry, inp):
-            x, idx = carry
-            p_l, cache_l = inp if with_cache else (inp, None)
-            ctx_l = dataclasses.replace(
-                ctx, key=None if ctx.key is None
-                else jax.random.fold_in(ctx.key, idx))
-            x, stats, nc = _hybrid_sb_apply(p_l, x, cfg, acfg, ctx_l,
-                                            positions, cache_l, seq_mask)
-            out = (stats, nc) if with_cache else stats
-            return (x, idx + 1), out
-    else:
-        ffn_kind = {"dense": "dense", "vlm": "dense", "audio": "dense",
-                    "moe": "moe", "ssm": "none"}[fam]
-
-        def body(carry, inp):
-            x, idx = carry
-            p_l, cache_l = inp if with_cache else (inp, None)
-            ctx_l = dataclasses.replace(
-                ctx, key=None if ctx.key is None
-                else jax.random.fold_in(ctx.key, idx))
-            if fam == "ssm":
-                x, stats, nc = apply_mamba_layer(p_l, x, cfg, acfg, ctx_l,
-                                                 cache_l, ffn_kind, seq_mask)
-            else:
-                x, stats, nc = apply_attn_layer(p_l, x, cfg, acfg, ctx_l,
-                                                positions, cache_l, ffn_kind,
-                                                seq_mask)
-            out = (stats, nc) if with_cache else stats
-            return (x, idx + 1), out
+    def body(carry, p_l):
+        x, idx, cache = carry
+        ctx_l = dataclasses.replace(
+            ctx, key=None if ctx.key is None
+            else jax.random.fold_in(ctx.key, idx))
+        if fam == "hybrid":
+            x, stats, cache = _hybrid_sb_apply(p_l, x, cfg, acfg, ctx_l,
+                                               positions, cache, (idx,),
+                                               seq_mask)
+        elif fam == "ssm":
+            x, stats, cache = apply_mamba_layer(p_l, x, cfg, acfg, ctx_l,
+                                                cache, ffn_kind, seq_mask,
+                                                (idx,))
+        else:
+            x, stats, cache = apply_attn_layer(p_l, x, cfg, acfg, ctx_l,
+                                               positions, cache, ffn_kind,
+                                               seq_mask, (idx,))
+        return (x, idx + 1, cache), stats
 
     if remat:
         # remat=True/'dots': save non-batched matmul outputs (XLA default
@@ -312,12 +319,8 @@ def apply_blocks(params_blocks, x, cfg, acfg: AnalogConfig, ctx: AnalogCtx,
                   else jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
         body = jax.checkpoint(body, policy=policy)
 
-    xs = (params_blocks, caches) if with_cache else params_blocks
-    (x, _), out = jax.lax.scan(body, (x, jnp.zeros((), jnp.int32)), xs)
-    if with_cache:
-        stats, new_caches = out
-    else:
-        stats, new_caches = out, None
+    (x, _, new_caches), stats = jax.lax.scan(
+        body, (x, jnp.zeros((), jnp.int32), caches), params_blocks)
     return x, stats, new_caches
 
 
@@ -469,7 +472,9 @@ def init_caches(cfg, batch: int, max_len: int, dtype=jnp.float32,
                 per_slot: bool = False, paged: bool = False,
                 kv_block_size: int = 16, kv_blocks: int | None = None,
                 kv_bits: int = 0, state_snaps: int = 0):
-    """Stacked per-layer decoding caches matching ``apply_blocks`` scan xs.
+    """Stacked per-layer decoding caches: every leaf leads with the layer
+    axis (hybrid: the super-block axis), and ``apply_blocks`` carries the
+    whole stack through its layer scan, each layer writing its own index.
 
     ``per_slot=True`` builds the continuous-batching slot layout: the
     attention caches carry per-row write cursors (``pos``/``start`` [B])
