@@ -557,22 +557,32 @@ def _step_jit(params, caches, toks, off, active, keys, counts, temp, topk,
 
 
 def _gather_rows(caches, idx, axes):
-    """Gather the cache rows of slots ``idx`` into a compact batch
-    (``-1``-axis pool leaves pass through whole)."""
-    return jax.tree.map(
-        lambda c, ax: c if ax < 0 else jnp.take(c, idx, axis=ax),
-        caches, axes)
+    """Gather the cache rows of slots ``idx`` into a compact batch: one
+    dynamic slice per row on each leaf's own slot axis, so only those
+    rows move (``-1``-axis pool leaves pass through whole)."""
+    def gather(c, ax):
+        if ax < 0:
+            return c
+        return jnp.concatenate(
+            [jax.lax.dynamic_slice_in_dim(c, idx[i], 1, ax)
+             for i in range(idx.shape[0])], axis=ax)
+
+    return jax.tree.map(gather, caches, axes)
 
 
 def _scatter_rows(caches, sub, idx, axes):
-    """Write a compact gathered batch back to its slots (``idx`` rows are
-    distinct by construction, so the scatter is order-independent; pool
-    leaves replace the old leaf — the prefill updated them in place)."""
+    """Write a compact gathered batch back to its slots, one in-place
+    dynamic update per row on each leaf's slot axis (``idx`` rows are
+    distinct by construction, so the order of the writes cannot matter;
+    pool leaves replace the old leaf — the prefill updated them in
+    place)."""
     def scat(c, s, ax):
         if ax < 0:
             return s
-        cm = jnp.moveaxis(c, ax, 0).at[idx].set(jnp.moveaxis(s, ax, 0))
-        return jnp.moveaxis(cm, 0, ax)
+        for i in range(idx.shape[0]):
+            c = jax.lax.dynamic_update_slice_in_dim(
+                c, jax.lax.slice_in_dim(s, i, i + 1, axis=ax), idx[i], ax)
+        return c
 
     return jax.tree.map(scat, caches, sub, axes)
 

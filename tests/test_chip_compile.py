@@ -6,8 +6,9 @@ Mosaic cannot legalize. These tests compile each Pallas kernel of the
 serving path for one chip of a described ``v5e:2x2`` topology at real
 widths: llama-3.2-1b for the attention and matmul kernels (d_model 2048,
 d_ff 8192, 32 query / 8 kv heads of 64, KV blocks of 16), mamba2-130m
-for ``ssd_scan`` (24 heads of 64, state 128). Nothing runs; a compile
-that passes is not a chip run.
+for ``ssd_scan`` (24 heads of 64, state 128). The serving engine's
+mixed step is compiled whole at a cut of the chat cell's phi-3-mini.
+Nothing runs; a compile that passes is not a chip run.
 
 The topology is described in a module fixture, never at import: only
 one process may load the TPU library, and the test workers all import
@@ -16,6 +17,7 @@ compiles (an entry compiled for a described chip cannot be read back
 without one).
 """
 
+import dataclasses
 import os
 
 import jax
@@ -23,12 +25,16 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_config
+from repro.core.analog import AnalogConfig
 from repro.kernels.analog_matmul import analog_matmul
 from repro.kernels.int4_matmul import int4_matmul
 from repro.kernels.paged_attention import paged_flash_decode
 from repro.kernels.paged_prefill import paged_flash_prefill
 from repro.kernels.ref import PACK_GROUP, round_up
 from repro.kernels.ssd_scan import ssd_scan
+from repro.models import transformer as T
+from repro.serve import scheduler as S
 
 D, D_FF, NQ, NKV, HD, BS = 2048, 8192, 32, 8, 64, 16   # llama-3.2-1b
 POOL, NB = 64, 8                                      # pool blocks, per slot
@@ -125,3 +131,46 @@ def test_ssd_scan_compiles(one_chip):
              ((bh, s, p), jnp.float32), ((bh, s), jnp.float32),
              ((bh,), jnp.float32), ((bh, s, n), jnp.float32),
              ((bh, s, n), jnp.float32))
+
+
+def test_mixed_step_moves_rows_not_the_cache(one_chip):
+    """The engine's fused mixed step for phi-3-mini's cache (32 heads of
+    96, bf16 KV) cut to 2 layers, 16 slots x 256 and a ``prefill_batch``
+    of 2: its slot gather and scatter hold no slot-major copy of the
+    cache, and its temporaries exceed the decode step's by less than one
+    whole K+V. The decode step, which gathers and scatters nothing, is
+    the base: where head dim is under 128 the chip's default layout of
+    the cache argument puts the positions minor, so every step holds
+    layout copies of K and V. The feed-forward and vocab widths are cut
+    so that the weights' temporaries do not hide the cache's."""
+    slots, max_len, pb, chunk = 16, 256, 2, 16
+    cfg = dataclasses.replace(get_config("phi-3-mini-4k"), num_layers=2,
+                              d_ff=256, vocab_size=512)
+    acfg = AnalogConfig(mode="analog", train_noise=False)
+    on_chip = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    params = on_chip(jax.eval_shape(
+        lambda k: T.init_model(k, cfg)[0], jax.random.PRNGKey(0)))
+    caches = on_chip(jax.eval_shape(lambda: T.init_caches(
+        cfg, slots, max_len, jnp.bfloat16, per_slot=True)))
+    f32, i32 = jnp.float32, jnp.int32
+    decode = on_chip([jax.ShapeDtypeStruct(s, d) for s, d in [
+        ((slots,), i32), ((slots,), i32), ((slots,), f32),
+        ((slots, 2), jnp.uint32), ((slots,), i32), ((slots,), f32),
+        ((slots,), i32), ((slots,), f32), ((slots,), i32)]])
+    prefill = on_chip(dict(
+        pf_idx=jax.ShapeDtypeStruct((pb,), i32),
+        pf_toks=jax.ShapeDtypeStruct((pb, chunk), i32),
+        pf_mask=jax.ShapeDtypeStruct((pb, chunk), f32),
+        pf_off=jax.ShapeDtypeStruct((pb,), i32)))
+    flags = dict(cfg=cfg, acfg=acfg, use_top_k=False, use_top_p=False, k=1)
+    step = S._step_jit.lower(params, caches, *decode, **flags).compile()
+    mixed = S._mixed_step_jit.lower(params, caches, *decode, **prefill,
+                                    **flags, paged=False).compile()
+    k = caches["k"]
+    slot_major = (k.shape[1], k.shape[0]) + k.shape[2:]
+    assert "bf16[%s]" % ",".join(map(str, slot_major)) not in mixed.as_text()
+    kv = 2 * k.size * k.dtype.itemsize
+    temp = lambda c: c.memory_analysis().temp_size_in_bytes
+    assert temp(mixed) - temp(step) < kv

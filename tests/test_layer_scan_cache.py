@@ -161,11 +161,9 @@ def test_layer_scan_matches_unrolled_layers(arch, layout, monkeypatch):
 def test_step_temporaries_hold_no_whole_cache_copy():
     """Compiled on the CPU at 4 layers, 8 slots x 256, f32 KV (f32: the
     CPU compiler widens a bf16 scatter through the whole buffer), the
-    decode step's temporaries stay below two layers' K+V: no layer copies
-    the stacked cache. The mixed step's compact prefill batch goes
-    through the slot gather and scatter of ``serve.scheduler``, which
-    still transpose the whole cache to slot-major once; its bound allows
-    that one copy and no more."""
+    decode step's and the mixed step's temporaries stay below two layers'
+    K+V: no layer copies the stacked cache, and the mixed step's slot
+    gather and scatter move only the compact prefill batch's rows."""
     cfg = dataclasses.replace(get_config("phi-3-mini-4k").reduce(),
                               num_layers=4)
     cfg, params, _ = build(cfg, jax.random.PRNGKey(0))
@@ -190,4 +188,78 @@ def test_step_temporaries_hold_no_whole_cache_copy():
                  pf_toks=jnp.zeros((pb, c), jnp.int32),
                  pf_mask=jnp.ones((pb, c), jnp.float32),
                  pf_off=jnp.zeros((pb,), jnp.int32), paged=False)
-    assert mixed < two_layers + kv
+    assert mixed < two_layers
+
+
+def _whole_leaf_gather(caches, idx, axes):
+    """The reference gather: ``take`` along each leaf's slot axis."""
+    return jax.tree.map(
+        lambda c, ax: c if ax < 0 else jnp.take(c, idx, axis=ax),
+        caches, axes)
+
+
+def _whole_leaf_scatter(caches, sub, idx, axes):
+    """The reference scatter: the whole leaf moved slot-major, its ``idx``
+    rows set, and moved back."""
+    def scat(c, s, ax):
+        if ax < 0:
+            return s
+        cm = jnp.moveaxis(c, ax, 0).at[idx].set(jnp.moveaxis(s, ax, 0))
+        return jnp.moveaxis(cm, 0, ax)
+
+    return jax.tree.map(scat, caches, sub, axes)
+
+
+ROW_LAYOUTS = {"contiguous": {}, "paged": dict(paged=True),
+               "paged-int8": dict(paged=True, kv_bits=8),
+               "paged-snaps": dict(paged=True, state_snaps=2)}
+ROW_CASES = [(arch, layout) for arch in FAMILIES for layout in ROW_LAYOUTS
+             if layout != "paged-snaps"
+             or get_config(arch).family in ("ssm", "hybrid")]
+
+
+def _random_like(tree, seed):
+    rng = np.random.default_rng(seed)
+    return jax.tree.map(lambda c: jnp.asarray(
+        rng.standard_normal(c.shape) * 4, c.dtype), tree)
+
+
+@pytest.mark.parametrize("idx", [[2, 0, 3], [1]],
+                         ids=["unsorted-filler", "one-row"])
+@pytest.mark.parametrize("arch,layout", ROW_CASES)
+def test_slot_gather_scatter_match_whole_leaf_forms(arch, layout, idx):
+    """The mixed step's per-row slot gather and scatter return every leaf
+    bitwise equal to the whole-leaf ``take`` / slot-major ``.at[].set``
+    forms: for an unsorted compact batch whose last row is a filler slot,
+    and for the one-row batch of the drafter's prefill."""
+    cfg = get_config(arch).reduce()
+    kw = ROW_LAYOUTS[layout]
+    caches = _random_like(T.init_caches(cfg, B, MAX_LEN, jnp.bfloat16,
+                                        per_slot=True, kv_block_size=BLOCK,
+                                        **kw), 3)
+    axes, _ = T.cache_slot_spec(cfg, paged=kw.get("paged", False),
+                                kv_bits=kw.get("kv_bits", 0),
+                                state_snaps="state_snaps" in kw)
+    idx = jnp.asarray(idx, jnp.int32)
+    gather = jax.jit(lambda c, i: S._gather_rows(c, i, axes))
+    ref_gather = jax.jit(lambda c, i: _whole_leaf_gather(c, i, axes))
+    sub = gather(caches, idx)
+    ref_sub = ref_gather(caches, idx)
+    assert jax.tree.structure(sub) == jax.tree.structure(ref_sub)
+    for (path, g), r in zip(jax.tree_util.tree_leaves_with_path(sub),
+                            jax.tree.leaves(ref_sub)):
+        np.testing.assert_array_equal(
+            np.asarray(g), np.asarray(r),
+            err_msg=f"gather {jax.tree_util.keystr(path)}")
+
+    new_sub = _random_like(ref_sub, 4)
+    scatter = jax.jit(lambda c, s, i: S._scatter_rows(c, s, i, axes))
+    ref_scatter = jax.jit(lambda c, s, i: _whole_leaf_scatter(c, s, i, axes))
+    got = scatter(caches, new_sub, idx)
+    ref = ref_scatter(caches, new_sub, idx)
+    assert jax.tree.structure(got) == jax.tree.structure(caches)
+    for (path, g), r in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree.leaves(ref)):
+        np.testing.assert_array_equal(
+            np.asarray(g), np.asarray(r),
+            err_msg=f"scatter {jax.tree_util.keystr(path)}")
